@@ -35,6 +35,7 @@ not duplicated.
 
 from __future__ import annotations
 
+import re
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -125,13 +126,17 @@ def callback_census(fn, *args) -> int:
     return count_jaxpr_prims(jaxpr, names=CALLBACK_PRIMS)
 
 
-def pallas_call_census(fn, *args) -> int:
+def pallas_call_census(fn, *args, kernel: Optional[str] = None) -> int:
     """pallas_call invocations in the traced ``fn`` (== packed sites on
-    the pallas backend)."""
+    the pallas backend).  ``kernel`` counts only the calls whose kernel
+    ``name=`` fully matches that regex (e.g. ``"nm_spmm_[0-9_]+(u4)?"``
+    — a train step also runs fused_update)."""
     import jax
     from repro.launch.hlo_cost import count_jaxpr_prims
     jaxpr = fn if _is_jaxpr(fn) else jax.make_jaxpr(fn)(*args)
-    return count_jaxpr_prims(jaxpr, names=("pallas_call",))
+    pred = None if kernel is None else (
+        lambda eqn: re.fullmatch(kernel, str(eqn.params.get("name"))))
+    return count_jaxpr_prims(jaxpr, names=("pallas_call",), pred=pred)
 
 
 def prunable_sites(master, sp_cfg) -> List[str]:

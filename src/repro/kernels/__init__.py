@@ -12,23 +12,21 @@ modules:
   nm_spmm(x, vals, idx, n, m, *, idx_bits=8)
       fused decompress-matmul: the dense weight tile exists only in
       VMEM.  ``idx_bits=4`` expands nibbles inside the kernel tile, so
-      the index plane crosses HBM at half width.  The pallas path falls
-      back to the bitwise-equal jnp oracle when a u4 tile cannot split
-      cleanly (odd compact rows per block); callers never see the
-      difference — the two widths are bitwise interchangeable by
-      construction and pinned so in tests/test_operand.py.
+      the index plane crosses HBM at half width.  The two widths are
+      bitwise interchangeable by construction and pinned so in
+      tests/test_operand.py.
   nm_spmm_shared / fused_update
       reduced-K shared-pattern matmul; fused SGD + re-sparsify weight
       update (emits u8 or u4 planes to match the operand).
   nm_compact_pallas / nm_spmm_pallas / nm_spmm_shared_pallas /
   fused_update_pallas
-      the raw pallas_call wrappers (explicit block sizes) behind the
-      jit'd dispatchers above — Pallas on TPU, interpret mode on CPU,
-      oracle with ``use_pallas=False``.
+      the raw pallas_call wrappers behind the jit'd dispatchers above
+      — compiled by Mosaic on TPU, interpret mode elsewhere, the oracle
+      with ``use_pallas=False``.
   decompress_nm(vals, idx, n, m, *, idx_bits=8)
-      the one shared (vals, idx) -> dense N:M expansion (select-based,
-      scatter-free) used by the kernel, the oracle and the operand
-      fallback alike; unpacks u4 nibbles first when ``idx_bits=4``.
+      the one XLA (vals, idx) -> dense N:M expansion (select-based,
+      scatter-free) used by the oracle and the operand's jnp backend;
+      unpacks u4 nibbles first when ``idx_bits=4``.
   pack_shared / packed_bytes
       host-side shared-mode packer + HBM byte accounting.
 """

@@ -5,7 +5,8 @@ update is fused with the N:M compaction so the FF/BP stages of the next
 iteration load only compact sparse weights — saving external-memory
 bandwidth and storage whenever sparsity > 50%.
 
-One grid step performs, on a (TR, TK) fp32 master-weight tile:
+One grid step performs, on a (128, W) fp32 master-weight tile (the
+layout and the selection helpers are nm_compact's):
 
   mask  = N:M survivor mask of w (SR-STE's sparse-refined target)
   g_eff = g + wd*w + lam*(1-mask)*w        # SR-STE regularized gradient
@@ -30,27 +31,25 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels import pallas_compat as pltpu
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.nm_compact import _select_topn
+from repro.kernels.nm_compact import (ROWS, idx_tile, idx_scratch,
+                                      planes_tile, select_topn_planes,
+                                      survivor_planes, tile_planes)
 
 
 def _fused_update_kernel(
     lr_ref, mu_ref, wd_ref, lam_ref,
     w_ref, g_ref, v_ref,
     w_out, v_out, vals_out, idx_out,
+    wt, vt, it,
     *, n: int, m: int,
 ):
-    tr, tk = w_ref.shape
     w = w_ref[...]
-    grp = w.reshape(tr, tk // m, m)
     # survivor mask of the *current* weights (pre-update), per SR-STE
-    _, keep_idx = _select_topn(grp, n, m)  # (TR, G, N) ascending
-    pos = jax.lax.broadcasted_iota(jnp.int32, grp.shape, 2)
-    mask = jnp.zeros(grp.shape, jnp.bool_)
-    for j in range(n):
-        mask = mask | (pos == keep_idx[..., j][..., None])
-    mask = mask.reshape(tr, tk)
+    _, keep_idx = select_topn_planes(tile_planes(wt, w, m), n)
+    keep = [k.astype(jnp.float32) for k in survivor_planes(keep_idx, m)]
+    mask = planes_tile(wt, keep) > 0.5
 
     lr = lr_ref[0, 0]
     mu = mu_ref[0, 0]
@@ -65,9 +64,9 @@ def _fused_update_kernel(
     w_out[...] = w_new
 
     # SORE: pack the updated weights along the last axis
-    pv, pi = _select_topn(w_new.reshape(tr, tk // m, m), n, m)
-    vals_out[...] = pv.reshape(tr, tk // m * n).astype(vals_out.dtype)
-    idx_out[...] = pi.reshape(tr, tk // m * n).astype(jnp.uint8)
+    pv, pi = select_topn_planes(tile_planes(wt, w_new, m), n)
+    vals_out[...] = planes_tile(vt, pv).astype(vals_out.dtype)
+    idx_out[...] = idx_tile(it, pi, 8)
 
 
 def fused_update_pallas(
@@ -81,39 +80,38 @@ def fused_update_pallas(
     n: int,
     m: int,
     *,
-    block_r: int = 256,
-    block_k: int = 512,
     interpret: bool = False,
 ):
+    """(R, W) fp32 master/grad/momentum -> (w', v', bf16 vals, u8 idx).
+
+    R must be a multiple of ``ROWS`` (``kernels.ops`` reshapes and pads
+    to that); the packed pair runs along the last axis."""
     r, k = w.shape
-    block_r = min(block_r, r)
-    block_k = min(block_k, k)
-    assert r % block_r == 0 and k % block_k == 0 and block_k % m == 0
-    kc_blk = block_k // m * n
-    grid = (r // block_r, k // block_k)
+    assert r % ROWS == 0 and k % m == 0, (r, k, m)
+    kc = k // m * n
     scal = lambda: pl.BlockSpec(  # noqa: E731
-        (1, 1), lambda i, j: (0, 0), memory_space=pltpu.MemorySpace.SMEM
+        (1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM
     )
     blk = lambda bk: pl.BlockSpec(  # noqa: E731
-        (block_r, bk), lambda i, j: (i, j), memory_space=pltpu.MemorySpace.VMEM
+        (ROWS, bk), lambda i: (i, 0), memory_space=pltpu.VMEM
     )
     as2d = lambda s: jnp.asarray(s, jnp.float32).reshape(1, 1)  # noqa: E731
     return pl.pallas_call(
         functools.partial(_fused_update_kernel, n=n, m=m),
-        grid=grid,
-        in_specs=[scal(), scal(), scal(), scal(), blk(block_k), blk(block_k), blk(block_k)],
-        out_specs=(blk(block_k), blk(block_k), blk(kc_blk), blk(kc_blk)),
+        grid=(r // ROWS,),
+        in_specs=[scal(), scal(), scal(), scal(), blk(k), blk(k), blk(k)],
+        out_specs=(blk(k), blk(k), blk(kc), blk(kc)),
         out_shape=(
             jax.ShapeDtypeStruct((r, k), jnp.float32),
             jax.ShapeDtypeStruct((r, k), jnp.float32),
-            jax.ShapeDtypeStruct((r, k // m * n), jnp.bfloat16),
-            jax.ShapeDtypeStruct((r, k // m * n), jnp.uint8),
+            jax.ShapeDtypeStruct((r, kc), jnp.bfloat16),
+            jax.ShapeDtypeStruct((r, kc), jnp.uint8),
         ),
+        scratch_shapes=[pltpu.VMEM((k, ROWS), jnp.float32),
+                        pltpu.VMEM((kc, ROWS), jnp.float32),
+                        idx_scratch(kc, 8)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=(
-                pltpu.GridDimensionSemantics.PARALLEL,
-                pltpu.GridDimensionSemantics.PARALLEL,
-            )
+            dimension_semantics=(pltpu.GridDimensionSemantics.PARALLEL,)
         ),
         interpret=interpret,
         name=f"fused_update_{n}_{m}",

@@ -16,10 +16,13 @@ Fig. 11c, applied to gradients per arXiv 2203.10991):
     f32 arithmetic: decoded + new_err == g + err bitwise.
 
 ``grad_decompress_mean_pallas``
-    All-gathered payloads (P, Kc) -> dense mean (1, K) without ever
-    materializing the P dense gradients: each grid step scatters its
-    packed tile into registers via m-way selects and reduces over the
-    pod axis in VMEM.
+    All-gathered payloads (P, R, Wc) -> dense mean (R, W) without ever
+    materializing the P dense gradients: each grid step expands its
+    packed tile with m-way selects and reduces over the pod axis in
+    VMEM.
+
+Both use nm_compact's layout: a (128, W) tile is transposed into VMEM
+and handled as per-offset planes, so groups never split the lane axis.
 """
 
 from __future__ import annotations
@@ -29,118 +32,95 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels import pallas_compat as pltpu
-from repro.kernels.nm_compact import _select_topn
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.nm_compact import (ROWS, idx_scratch, idx_tile,
+                                      planes_tile, select_topn_planes,
+                                      survivor_planes, tile_planes)
+from repro.kernels.ref import bf16_round
 
 
-def _scatter_groups(vals_f32: jax.Array, idx: jax.Array, n: int, m: int):
-    """(..., G, n) packed -> (..., G, m) dense, select-based (Mosaic-safe)."""
-    shape = vals_f32.shape[:-1] + (m,)
-    pos = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
-    out = jnp.zeros(shape, jnp.float32)
-    for s in range(n):
-        sel = pos == idx[..., s : s + 1].astype(jnp.int32)
-        out = out + jnp.where(sel, vals_f32[..., s : s + 1], 0.0)
-    return out
-
-
-def _compress_kernel(g_ref, e_ref, vals_ref, idx_ref, err_ref, *, n: int, m: int):
-    tr, tk = g_ref.shape
+def _compress_kernel(g_ref, e_ref, vals_ref, idx_ref, err_ref, tt, vt, it,
+                     *, n: int, m: int):
     t = g_ref[...].astype(jnp.float32) + e_ref[...].astype(jnp.float32)
-    tg = t.reshape(tr, tk // m, m)
-    v, i = _select_topn(tg, n, m)
-    sent = v.astype(jnp.bfloat16)
+    planes = tile_planes(tt, t, m)
+    v, i = select_topn_planes(planes, n)
     # the residual must see the *wire* (bf16-rounded) values, so the
     # rounding error is carried forward rather than silently dropped
-    dec = _scatter_groups(sent.astype(jnp.float32), i, n, m)
-    vals_ref[...] = sent.reshape(tr, (tk // m) * n)
-    idx_ref[...] = i.reshape(tr, (tk // m) * n).astype(jnp.uint8)
-    err_ref[...] = (tg - dec).reshape(tr, tk)
+    err = [jnp.where(kept, p - bf16_round(p), p)
+           for kept, p in zip(survivor_planes(i, m), planes)]
+    vals_ref[...] = planes_tile(vt, v).astype(jnp.bfloat16)
+    idx_ref[...] = idx_tile(it, i, 8)
+    err_ref[...] = planes_tile(tt, err)
 
 
-def grad_compress_pallas(
-    g: jax.Array,
-    e: jax.Array,
-    n: int,
-    m: int,
-    *,
-    block_r: int = 8,
-    block_k: int = 1024,
-    interpret: bool = False,
-):
-    """(R, K) grads + residual -> bf16 vals, uint8 idx (R, K*n/m), err (R, K)."""
+def grad_compress_pallas(g: jax.Array, e: jax.Array, n: int, m: int, *,
+                         interpret: bool = False):
+    """(R, W) grads + residual -> bf16 vals, uint8 idx (R, W*n/m), err
+    (R, W).  R must be a multiple of ``ROWS``."""
     r, k = g.shape
-    block_r = min(block_r, r)
-    block_k = min(block_k, k)
-    assert k % m == 0 and block_k % m == 0, (k, block_k, m)
-    assert r % block_r == 0 and k % block_k == 0, (r, k, block_r, block_k)
-    kc_blk = block_k // m * n
-    grid = (r // block_r, k // block_k)
-    vmem = pltpu.MemorySpace.VMEM
-    out_shape = (
-        jax.ShapeDtypeStruct((r, k // m * n), jnp.bfloat16),
-        jax.ShapeDtypeStruct((r, k // m * n), jnp.uint8),
-        jax.ShapeDtypeStruct((r, k), jnp.float32),
-    )
+    assert k % m == 0 and r % ROWS == 0, (r, k, m)
+    kc = k // m * n
+    blk = lambda bk: pl.BlockSpec(  # noqa: E731
+        (ROWS, bk), lambda i: (i, 0), memory_space=pltpu.VMEM)
     return pl.pallas_call(
         functools.partial(_compress_kernel, n=n, m=m),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_r, block_k), lambda i, j: (i, j), memory_space=vmem),
-            pl.BlockSpec((block_r, block_k), lambda i, j: (i, j), memory_space=vmem),
-        ],
-        out_specs=(
-            pl.BlockSpec((block_r, kc_blk), lambda i, j: (i, j), memory_space=vmem),
-            pl.BlockSpec((block_r, kc_blk), lambda i, j: (i, j), memory_space=vmem),
-            pl.BlockSpec((block_r, block_k), lambda i, j: (i, j), memory_space=vmem),
+        grid=(r // ROWS,),
+        in_specs=[blk(k), blk(k)],
+        out_specs=(blk(kc), blk(kc), blk(k)),
+        out_shape=(
+            jax.ShapeDtypeStruct((r, kc), jnp.bfloat16),
+            jax.ShapeDtypeStruct((r, kc), jnp.uint8),
+            jax.ShapeDtypeStruct((r, k), jnp.float32),
         ),
-        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((k, ROWS), jnp.float32),
+                        pltpu.VMEM((kc, ROWS), jnp.float32),
+                        idx_scratch(kc, 8)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=(
-                pltpu.GridDimensionSemantics.PARALLEL,
-                pltpu.GridDimensionSemantics.PARALLEL,
-            )
+            dimension_semantics=(pltpu.GridDimensionSemantics.PARALLEL,)
         ),
         interpret=interpret,
         name=f"grad_compress_{n}_{m}",
     )(g, e)
 
 
-def _decompress_mean_kernel(vals_ref, idx_ref, out_ref, *, n: int, m: int):
-    p, ck = vals_ref.shape
-    v = vals_ref[...].astype(jnp.float32).reshape(p, ck // n, n)
-    i = idx_ref[...].reshape(p, ck // n, n)
-    dec = _scatter_groups(v, i, n, m)  # (P, G, m)
-    out_ref[...] = (dec.sum(axis=0) / p).reshape(1, (ck // n) * m)
+def _decompress_mean_kernel(vals_ref, idx_ref, out_ref, vt, it, dt,
+                            *, n: int, m: int):
+    p_count = vals_ref.shape[0]
+    g = vals_ref.shape[2] // n
+    acc = [jnp.zeros((g, ROWS), jnp.float32) for _ in range(m)]
+    for p in range(p_count):
+        vt[...] = vals_ref[p].astype(jnp.float32).T
+        it[...] = idx_ref[p].astype(jnp.int32).T
+        v = [vt[pl.ds(j, g, stride=n), :] for j in range(n)]
+        i = [it[pl.ds(j, g, stride=n), :] for j in range(n)]
+        for s in range(m):
+            dec = jnp.where(i[0] == s, v[0], 0.0)
+            for vj, ij in zip(v[1:], i[1:]):
+                dec = dec + jnp.where(ij == s, vj, 0.0)
+            acc[s] = acc[s] + dec
+    out_ref[...] = planes_tile(dt, [a / p_count for a in acc])
 
 
-def grad_decompress_mean_pallas(
-    vals: jax.Array,
-    idx: jax.Array,
-    n: int,
-    m: int,
-    *,
-    block_c: int = 1024,
-    interpret: bool = False,
-):
-    """All-gathered packed payloads (P, Kc) -> pod-mean dense (1, K) f32."""
-    p, kc = vals.shape
-    block_c = min(block_c, kc)
-    assert kc % n == 0 and block_c % n == 0, (kc, block_c, n)
-    assert kc % block_c == 0, (kc, block_c)
-    k = kc // n * m
-    k_blk = block_c // n * m
-    grid = (kc // block_c,)
-    vmem = pltpu.MemorySpace.VMEM
+def grad_decompress_mean_pallas(vals: jax.Array, idx: jax.Array, n: int,
+                                m: int, *, interpret: bool = False):
+    """All-gathered packed payloads (P, R, Wc) -> pod-mean dense (R, W)
+    f32, W = Wc*m/n.  R must be a multiple of ``ROWS``."""
+    p, r, wc = vals.shape
+    assert wc % n == 0 and r % ROWS == 0, (p, r, wc, n)
+    w = wc // n * m
+    blk = pl.BlockSpec((p, ROWS, wc), lambda i: (0, i, 0),
+                       memory_space=pltpu.VMEM)
     return pl.pallas_call(
         functools.partial(_decompress_mean_kernel, n=n, m=m),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((p, block_c), lambda j: (0, j), memory_space=vmem),
-            pl.BlockSpec((p, block_c), lambda j: (0, j), memory_space=vmem),
-        ],
-        out_specs=pl.BlockSpec((1, k_blk), lambda j: (0, j), memory_space=vmem),
-        out_shape=jax.ShapeDtypeStruct((1, k), jnp.float32),
+        grid=(r // ROWS,),
+        in_specs=[blk, blk],
+        out_specs=pl.BlockSpec((ROWS, w), lambda i: (i, 0),
+                               memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((r, w), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((wc, ROWS), jnp.float32),
+                        pltpu.VMEM((wc, ROWS), jnp.int32),
+                        pltpu.VMEM((w, ROWS), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.GridDimensionSemantics.PARALLEL,)
         ),

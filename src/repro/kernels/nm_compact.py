@@ -3,15 +3,24 @@
 The paper's SORE is a 32-lane array of top-K sorters that turns a dense
 M-group stream into (top-N values, within-group indices) in M cycles.
 The TPU-native analogue is a VMEM-tiled vector kernel: each grid step
-loads a (TR, TK) tile, selects the N largest-|x| per consecutive-M group
+loads a (128, W) tile, selects the N largest-|x| per consecutive-M group
 with a strictly-earlier-index tie-break (exactly what a greater-than-only
-hardware sorter does), and writes the packed (TR, TK*N/M) values and
+hardware sorter does), and writes the packed (128, W*N/M) values and
 uint8 offsets.
 
-Selection is done with N rounds of masked max (no argsort — Mosaic-safe),
-then an N-element index sorting network so survivors appear in ascending
-group offset, matching the ``ref.py``/`nm_pack` layout and the compact
-format of Mishra et al. (the paper's [21]).
+Layout: Mosaic cannot split the lane axis into (W/M, M) groups, so the
+tile is transposed into a VMEM scratch — groups then run down sublanes —
+and read back as M *planes*, plane s holding in-group offset s of every
+group (a sublane-strided load; strided VMEM access needs 32-bit data in
+a 128-lane memref, which fixes the row block at 128).  Selection is then
+elementwise across the planes: N rounds of max (no argsort), then an
+N-element sorting network so survivors appear in ascending group offset,
+matching the ``ref.py``/`nm_pack` layout and the compact format of
+Mishra et al. (the paper's [21]).  Packed planes go back through a
+strided store and a transpose.
+
+The helpers below (``tile_planes``, ``select_topn_planes``,
+``planes_tile``) are shared with fused_update and grad_compress.
 """
 
 from __future__ import annotations
@@ -21,122 +30,140 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels import pallas_compat as pltpu
+from jax.experimental.pallas import tpu as pltpu
 
-_NEG = -jnp.inf
+# Row block of every lane-grouped kernel: the transposed tile is a
+# (W, ROWS) scratch, and Mosaic's strided loads/stores need 128 lanes.
+ROWS = 128
 
 
-def _select_topn(g: jax.Array, n: int, m: int):
-    """g: (..., G, M) -> (vals (..., G, N), idx (..., G, N)) sorted by idx."""
-    f32 = g.astype(jnp.float32)
-    pos = jax.lax.broadcasted_iota(jnp.int32, g.shape, g.ndim - 1)
-    # ties broken exactly: each round takes the *first* position attaining
-    # the max (j = min position where score == max), so earlier index wins.
-    score = jnp.abs(f32)
+def tile_planes(scratch, tile: jax.Array, m: int) -> list:
+    """(ROWS, W) f32 tile -> m planes (W/m, ROWS), plane s = offset s."""
+    scratch[...] = tile.T
+    g = tile.shape[1] // m
+    return [scratch[pl.ds(s, g, stride=m), :] for s in range(m)]
+
+
+def planes_tile(scratch, planes: list) -> jax.Array:
+    """Inverse of :func:`tile_planes`: k planes (G, ROWS) -> (ROWS, G*k),
+    plane j landing on columns j, j+k, j+2k, ..."""
+    k = len(planes)
+    g = planes[0].shape[0]
+    for j, p in enumerate(planes):
+        scratch[pl.ds(j, g, stride=k), :] = p
+    return scratch[...].T
+
+
+def select_topn_planes(planes: list, n: int):
+    """m value planes -> (n value planes, n int32 offset planes).
+
+    Per group (one element of every plane): the n largest |x|, ties to
+    the earlier offset (each round takes the first offset attaining the
+    max), returned in ascending offset order.  Values are selected, not
+    summed, so they come back bit for bit (signed zeros included)."""
+    m = len(planes)
+    score = [jnp.abs(p.astype(jnp.float32)) for p in planes]
     vals, idxs = [], []
-    remaining = score
     for _ in range(n):
-        mx = jnp.max(remaining, axis=-1, keepdims=True)
-        hit = remaining == mx
-        # first position attaining the max
-        j = jnp.min(jnp.where(hit, pos, m), axis=-1, keepdims=True)
-        sel = pos == j
-        vals.append(jnp.sum(jnp.where(sel, g, 0), axis=-1))
-        idxs.append(j[..., 0])
-        remaining = jnp.where(sel, _NEG, remaining)
+        mx = functools.reduce(jnp.maximum, score)
+        j = jnp.full(mx.shape, m, jnp.int32)
+        for s in reversed(range(m)):
+            j = jnp.where(score[s] == mx, s, j)
+        v = planes[0]
+        for s in range(1, m):
+            v = jnp.where(j == s, planes[s], v)
+        vals.append(v)
+        idxs.append(j)
+        score = [jnp.where(j == s, -jnp.inf, sc) for s, sc in enumerate(score)]
     # sort the n (val, idx) pairs ascending by idx — O(n^2) network, n tiny
     for a in range(n):
         for b in range(a + 1, n):
             swap = idxs[a] > idxs[b]
-            ia, ib = idxs[a], idxs[b]
-            va, vb = vals[a], vals[b]
+            ia, ib, va, vb = idxs[a], idxs[b], vals[a], vals[b]
             idxs[a] = jnp.where(swap, ib, ia)
             idxs[b] = jnp.where(swap, ia, ib)
             vals[a] = jnp.where(swap, vb, va)
             vals[b] = jnp.where(swap, va, vb)
-    return jnp.stack(vals, axis=-1), jnp.stack(idxs, axis=-1)
+    return vals, idxs
 
 
-def _compact_kernel(x_ref, vals_ref, idx_ref, *, n: int, m: int,
-                    idx_bits: int = 8):
-    tr, tk = x_ref.shape
-    g = x_ref[...].reshape(tr, tk // m, m)
-    v, i = _select_topn(g, n, m)
-    kc = (tk // m) * n
-    vals_ref[...] = v.reshape(tr, kc).astype(vals_ref.dtype)
-    if idx_bits == 4:
-        # two offsets per byte, low nibble first — the SORE output in the
-        # ceil(log2 M)-bit storage format (arXiv 2102.04010); the byte-wide
-        # index never exists outside this tile
-        pair = i.reshape(tr, kc // 2, 2).astype(jnp.uint8)
-        idx_ref[...] = pair[..., 0] | (pair[..., 1] << 4)
-    else:
-        idx_ref[...] = i.reshape(tr, kc).astype(jnp.uint8)
+def survivor_planes(idxs: list, m: int) -> list:
+    """n offset planes -> m bool planes: is offset s kept in its group."""
+    out = []
+    for s in range(m):
+        hit = idxs[0] == s
+        for i in idxs[1:]:
+            hit = hit | (i == s)
+        out.append(hit)
+    return out
 
 
-def nm_compact_pallas(
-    x: jax.Array,
-    n: int,
-    m: int,
-    *,
-    block_r: int = 256,
-    block_k: int = 512,
-    idx_bits: int = 8,
-    interpret: bool = False,
-):
-    """Pack (R, K) -> values (R, K*n/m), idx uint8 along the last axis.
+def idx_tile(scratch, idxs: list, idx_bits: int) -> jax.Array:
+    """n int32 offset planes -> the (ROWS, Kc) uint8 index block, or the
+    u4 plane (ROWS, ceil(Kc/2)): two offsets per byte, low nibble first
+    (``core.sparsity.pack_idx_u4`` layout; an odd Kc pads a zero high
+    nibble)."""
+    k = len(idxs)
+    g = idxs[0].shape[0]
+    for j, p in enumerate(idxs):
+        scratch[pl.ds(j, g, stride=k), :] = p
+    kc = g * k
+    if idx_bits == 8:
+        return scratch[...].T.astype(jnp.uint8)
+    half = (kc + 1) // 2
+    if kc % 2:
+        scratch[pl.ds(kc, 1), :] = jnp.zeros((1, ROWS), jnp.int32)
+    lo = scratch[pl.ds(0, half, stride=2), :]
+    hi = scratch[pl.ds(1, half, stride=2), :]
+    return (lo | (hi << 4)).T.astype(jnp.uint8)
 
-    ``idx_bits=4`` emits the u4 index plane (R, K*n/m/2) straight from
-    the selection tile — two in-group offsets per byte, low nibble first
-    (``core.sparsity.pack_idx_u4`` layout).  Needs an even per-tile
-    compact length, which every even ``n`` guarantees.
-    """
-    r, k = x.shape
-    block_r = min(block_r, r)
-    block_k = min(block_k, k)
-    assert k % m == 0 and block_k % m == 0, (k, block_k, m)
-    assert r % block_r == 0 and k % block_k == 0, (r, k, block_r, block_k)
-    kc_blk = block_k // m * n
-    if idx_bits == 4:
-        assert kc_blk % 2 == 0, (
-            f"u4 compact tiles must be even, got block_kc={kc_blk}")
-    idx_blk = kc_blk // 2 if idx_bits == 4 else kc_blk
-    grid = (r // block_r, k // block_k)
-    kc = k // m * n
-    out_shape = (
-        jax.ShapeDtypeStruct((r, kc), x.dtype),
-        jax.ShapeDtypeStruct((r, kc // 2 if idx_bits == 4 else kc),
-                             jnp.uint8),
-    )
+
+def idx_scratch(kc: int, idx_bits: int):
+    """VMEM scratch for :func:`idx_tile` (u4: room for an odd Kc's pad
+    row)."""
+    return pltpu.VMEM((kc + kc % 2 if idx_bits == 4 else kc, ROWS),
+                      jnp.int32)
+
+
+def _compact_kernel(x_ref, vals_ref, idx_ref, xt, vt, it, *, n: int, m: int,
+                    idx_bits: int):
+    planes = tile_planes(xt, x_ref[...].astype(jnp.float32), m)
+    v, i = select_topn_planes(planes, n)
+    vals_ref[...] = planes_tile(vt, v).astype(vals_ref.dtype)
+    idx_ref[...] = idx_tile(it, i, idx_bits)
+
+
+def nm_compact_pallas(x: jax.Array, n: int, m: int, *, idx_bits: int = 8,
+                      interpret: bool = False):
+    """Pack (R, W) -> values (R, W*n/m), idx uint8 along the last axis.
+
+    R must be a multiple of :data:`ROWS` (``kernels.ops`` reshapes and
+    pads to that).  ``idx_bits=4`` emits the u4 index plane
+    (R, ceil(W*n/m/2)) straight from the selection tile."""
+    r, w = x.shape
+    assert w % m == 0 and r % ROWS == 0, (r, w, m)
+    kc = w // m * n
+    kci = (kc + 1) // 2 if idx_bits == 4 else kc
     return pl.pallas_call(
         functools.partial(_compact_kernel, n=n, m=m, idx_bits=idx_bits),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(
-                (block_r, block_k),
-                lambda i, j: (i, j),
-                memory_space=pltpu.MemorySpace.VMEM,
-            )
-        ],
+        grid=(r // ROWS,),
+        in_specs=[pl.BlockSpec((ROWS, w), lambda i: (i, 0),
+                               memory_space=pltpu.VMEM)],
         out_specs=(
-            pl.BlockSpec(
-                (block_r, kc_blk),
-                lambda i, j: (i, j),
-                memory_space=pltpu.MemorySpace.VMEM,
-            ),
-            pl.BlockSpec(
-                (block_r, idx_blk),
-                lambda i, j: (i, j),
-                memory_space=pltpu.MemorySpace.VMEM,
-            ),
+            pl.BlockSpec((ROWS, kc), lambda i: (i, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((ROWS, kci), lambda i: (i, 0),
+                         memory_space=pltpu.VMEM),
         ),
-        out_shape=out_shape,
+        out_shape=(
+            jax.ShapeDtypeStruct((r, kc), x.dtype),
+            jax.ShapeDtypeStruct((r, kci), jnp.uint8),
+        ),
+        scratch_shapes=[pltpu.VMEM((w, ROWS), jnp.float32),
+                        pltpu.VMEM((kc, ROWS), jnp.float32),
+                        idx_scratch(kc, idx_bits)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=(
-                pltpu.GridDimensionSemantics.PARALLEL,
-                pltpu.GridDimensionSemantics.PARALLEL,
-            )
-        ),
+            dimension_semantics=(pltpu.GridDimensionSemantics.PARALLEL,)),
         interpret=interpret,
         name=f"nm_compact_{n}_{m}" + ("_u4" if idx_bits == 4 else ""),
     )(x)

@@ -12,7 +12,11 @@ in the BP pass of training.  Each grid step:
 
   1. streams a compact (TKc, TF) value tile + its offsets into VMEM,
   2. decompresses to a dense (TK, TF) tile entirely in VMEM
-     (M-way select against the offset plane — no gather needed),
+     (M-way select against the offset plane — no gather needed).  The
+     group members of compact row g*N+j sit N rows apart, and dense row
+     g*M+s M rows apart, so both sides move through 32-bit VMEM scratch
+     with sublane-strided loads and stores (Mosaic's strided access needs
+     32-bit data in a 128-lane memref: TF is 128),
   3. feeds the MXU a dense (TB, TK) x (TK, TF) partial matmul,
   4. accumulates over the K grid axis in an fp32 VMEM tile.
 
@@ -34,38 +38,39 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels import pallas_compat as pltpu
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _decompress(vals, idx, n: int, m: int, idx_bits: int = 8):
-    """(TKc, TF) packed -> (TK, TF) dense, TK = TKc*m/n.
-
-    Delegates to the package-wide select-based helper (one decompress
-    implementation for the kernel, the oracle and the operand fallback).
-    With ``idx_bits=4`` the index tile is the u4 plane (TKc//2, TF) and
-    the nibble expansion happens here, inside the tile — the byte-wide
-    index never exists in HBM and the dense weight never leaves VMEM.
-    """
-    from repro.kernels.nm_spmm_shared import decompress_nm
-
-    return decompress_nm(vals, idx, n, m, axis=0, idx_bits=idx_bits)
-
-
-def _spmm_kernel(act_ref, vals_ref, idx_ref, out_ref, *, n: int, m: int,
-                 nk: int, idx_bits: int = 8):
-    k_step = pl.program_id(2)
-
-    @pl.when(k_step == 0)
+def _spmm_kernel(act_ref, vals_ref, idx_ref, out_ref, vt, it, wt, *, n: int,
+                 m: int, idx_bits: int = 8):
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    w_dense = _decompress(vals_ref[...], idx_ref[...], n, m, idx_bits)
-    acc = jnp.dot(
+    kc = vals_ref.shape[0]
+    g = kc // n
+    vt[...] = vals_ref[...].astype(jnp.float32)
+    idx = idx_ref[...].astype(jnp.int32)
+    if idx_bits == 4:
+        # nibble expansion inside the tile: byte r holds compact rows 2r
+        # (low nibble) and 2r+1 — the byte-wide index never hits HBM
+        half = idx.shape[0]
+        it[pl.ds(0, half, stride=2), :] = idx & 0xF
+        it[pl.ds(1, half, stride=2), :] = idx >> 4
+    else:
+        it[...] = idx
+    v = [vt[pl.ds(j, g, stride=n), :] for j in range(n)]
+    i = [it[pl.ds(j, g, stride=n), :] for j in range(n)]
+    for s in range(m):
+        d = jnp.where(i[0] == s, v[0], 0.0)
+        for vj, ij in zip(v[1:], i[1:]):
+            d = d + jnp.where(ij == s, vj, 0.0)
+        wt[pl.ds(s, g, stride=m), :] = d
+    out_ref[...] += jnp.dot(
         act_ref[...],
-        w_dense.astype(act_ref.dtype),
+        wt[...].astype(act_ref.dtype),
         preferred_element_type=jnp.float32,
     )
-    out_ref[...] += acc
 
 
 def nm_spmm_pallas(
@@ -83,12 +88,12 @@ def nm_spmm_pallas(
 ):
     """act (B, K) @ packed weights (Kc=K*n/m, F) -> (B, F) fp32.
 
-    ``idx_bits=4`` consumes the u4-packed index plane (Kc//2, F): the
-    index BlockSpec streams half the bytes per tile and the nibble
+    ``idx_bits=4`` consumes the u4-packed index plane (ceil(Kc/2), F):
+    the index BlockSpec streams half the bytes per tile and the nibble
     expansion is fused into the tile decompress, so decode moves
     ``Kc*F`` value bytes + ``Kc*F/2`` index bytes and nothing dense.
-    Requires an even per-tile compact length (any even ``n`` satisfies
-    it); ``kernels.ops.nm_spmm`` falls back to jnp otherwise.
+    With more than one K tile the per-tile compact length must be even
+    (a byte never straddles two tiles).
     """
     b, k = act.shape
     kc, f = vals.shape
@@ -100,42 +105,46 @@ def nm_spmm_pallas(
     assert block_k % m == 0
     block_kc = block_k // m * n
     if idx_bits == 4:
-        assert kc % 2 == 0 and block_kc % 2 == 0, (
-            f"u4 pallas path needs even compact tiles, got Kc={kc}, "
-            f"block_kc={block_kc}")
-        assert idx.shape == (kc // 2, f), (idx.shape, kc, f)
-        block_kci = block_kc // 2
+        assert block_kc % 2 == 0 or block_k == k, (
+            f"u4 tiles must be even, got block_kc={block_kc}")
+        assert idx.shape == ((kc + 1) // 2, f), (idx.shape, kc, f)
+        block_kci = (block_kc + 1) // 2
     else:
         assert idx.shape == vals.shape
         block_kci = block_kc
-    nk = k // block_k
-    grid = (b // block_b, f // block_f, nk)
+    grid = (b // block_b, f // block_f, k // block_k)
     return pl.pallas_call(
-        functools.partial(_spmm_kernel, n=n, m=m, nk=nk, idx_bits=idx_bits),
+        functools.partial(_spmm_kernel, n=n, m=m, idx_bits=idx_bits),
         grid=grid,
         in_specs=[
             pl.BlockSpec(
                 (block_b, block_k),
                 lambda i, j, kk: (i, kk),
-                memory_space=pltpu.MemorySpace.VMEM,
+                memory_space=pltpu.VMEM,
             ),
             pl.BlockSpec(
                 (block_kc, block_f),
                 lambda i, j, kk: (kk, j),
-                memory_space=pltpu.MemorySpace.VMEM,
+                memory_space=pltpu.VMEM,
             ),
             pl.BlockSpec(
                 (block_kci, block_f),
                 lambda i, j, kk: (kk, j),
-                memory_space=pltpu.MemorySpace.VMEM,
+                memory_space=pltpu.VMEM,
             ),
         ],
         out_specs=pl.BlockSpec(
             (block_b, block_f),
             lambda i, j, kk: (i, j),
-            memory_space=pltpu.MemorySpace.VMEM,
+            memory_space=pltpu.VMEM,
         ),
         out_shape=jax.ShapeDtypeStruct((b, f), jnp.float32),
+        scratch_shapes=[
+            pltpu.VMEM((block_kc, block_f), jnp.float32),
+            pltpu.VMEM((2 * block_kci if idx_bits == 4 else block_kc,
+                        block_f), jnp.int32),
+            pltpu.VMEM((block_k, block_f), jnp.float32),
+        ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 pltpu.GridDimensionSemantics.PARALLEL,

@@ -14,9 +14,11 @@ Layout:
   rows: (nf, Kc) int32 absolute K indices of the survivors (ascending)
   out : (B, nf*TF) fp32
 
-Grid is (B tiles, F tiles); the full K row-panel of activations for a B
-tile is held in VMEM (bounded by ops.py; falls back to the oracle when it
-would not fit) and the gather is a one-shot ``jnp.take`` along lanes.
+Grid is (B tiles, F tiles); the full K panel of activations for a B
+tile is held in VMEM, transposed and in f32 (bounded by ops.py, which
+raises when it would not fit), and the survivors' rows are gathered by
+index from SMEM into a (Kc, TB) panel that the MXU contracts against
+the tile's packed weights.
 """
 
 from __future__ import annotations
@@ -26,16 +28,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels import pallas_compat as pltpu
+from jax.experimental.pallas import tpu as pltpu
 
 
 # ---------------------------------------------------------------------------
 # Shared decompress helper — THE (vals, idx) -> dense expansion
 # ---------------------------------------------------------------------------
 #
-# One implementation of the element-mode N:M decompression, used by the
-# nm_spmm Pallas kernel (per VMEM tile), the ref.py oracle and the
-# core/operand jnp fallback.  Select-based (an M-way select against the
+# One XLA implementation of the element-mode N:M decompression, used by
+# the ref.py oracle and the core/operand jnp backend (the nm_spmm kernel
+# computes the same selects plane by plane in VMEM, tests/test_operand
+# pins the two bitwise).  Select-based (an M-way select against the
 # offset plane), so it lowers scatter-free — O(K*F) vector work that
 # pipelines away against the MXU matmul.  Exact: packed values are kept
 # verbatim and every in-group offset hits exactly one slot, so the
@@ -91,12 +94,20 @@ def decompress_nm(vals: jax.Array, idx: jax.Array, n: int, m: int,
     return dense.reshape(shape[:axis] + (g * m,) + shape[axis + 1:])
 
 
-def _spmm_shared_kernel(act_ref, vals_ref, rows_ref, out_ref):
-    rows = rows_ref[0, :]  # (Kc,) int32, ascending within each M-group
-    act_g = jnp.take(act_ref[...], rows, axis=1)  # (TB, Kc)
-    out_ref[...] = jnp.dot(
-        act_g,
-        vals_ref[0].astype(act_ref.dtype),
+def _spmm_shared_kernel(rows_ref, act_t_ref, vals_ref, out_ref, gt, *,
+                        act_dtype):
+    # gather the Kc surviving activation rows of this F tile from the
+    # transposed (K, TB) panel: one dynamic-offset row copy per survivor
+    # (32-bit rows — Mosaic has no lane gather)
+    def copy_row(c, carry):
+        gt[pl.ds(c, 1), :] = act_t_ref[pl.ds(rows_ref[0, 0, c], 1), :]
+        return carry
+
+    jax.lax.fori_loop(0, gt.shape[0], copy_row, 0)
+    out_ref[...] = jax.lax.dot_general(
+        gt[...].astype(act_dtype),
+        vals_ref[0].astype(act_dtype),
+        (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
 
@@ -116,31 +127,32 @@ def nm_spmm_shared_pallas(
     assert b % block_b == 0
     grid = (b // block_b, nf)
     return pl.pallas_call(
-        _spmm_shared_kernel,
+        functools.partial(_spmm_shared_kernel, act_dtype=act.dtype),
         grid=grid,
         in_specs=[
             pl.BlockSpec(
-                (block_b, k),
-                lambda i, j: (i, 0),
-                memory_space=pltpu.MemorySpace.VMEM,
+                (1, 1, kc),
+                lambda i, j: (j, 0, 0),
+                memory_space=pltpu.SMEM,
+            ),
+            pl.BlockSpec(
+                (k, block_b),
+                lambda i, j: (0, i),
+                memory_space=pltpu.VMEM,
             ),
             pl.BlockSpec(
                 (1, kc, tf),
                 lambda i, j: (j, 0, 0),
-                memory_space=pltpu.MemorySpace.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, kc),
-                lambda i, j: (j, 0),
-                memory_space=pltpu.MemorySpace.VMEM,
+                memory_space=pltpu.VMEM,
             ),
         ],
         out_specs=pl.BlockSpec(
             (block_b, tf),
             lambda i, j: (i, j),
-            memory_space=pltpu.MemorySpace.VMEM,
+            memory_space=pltpu.VMEM,
         ),
         out_shape=jax.ShapeDtypeStruct((b, nf * tf), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((kc, block_b), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 pltpu.GridDimensionSemantics.PARALLEL,
@@ -149,4 +161,4 @@ def nm_spmm_shared_pallas(
         ),
         interpret=interpret,
         name="nm_spmm_shared",
-    )(act, vals, rows)
+    )(rows.reshape(nf, 1, kc), act.astype(jnp.float32).T, vals)

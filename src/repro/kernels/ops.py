@@ -1,19 +1,23 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On TPU the kernels run compiled; on CPU (this container) they run in
-``interpret=True`` mode, which executes the kernel body op-by-op and is
-what the test-suite validates against the ``ref.py`` oracles.
+``use_pallas=True`` (the default here) runs the Pallas kernel: compiled
+by Mosaic on TPU, in ``interpret=True`` mode elsewhere, which executes
+the kernel body op by op and is what the test suite checks against the
+``ref.py`` oracles.  ``use_pallas=False`` runs the jnp oracle (or its
+bitwise-equal vectorized form) through XLA.
 
-``use_pallas=False`` (the default for model code, the dry-run and the
-benchmarks) routes to the oracle implementations — XLA fuses them well
-and keeps the lowered HLO clean for roofline accounting.  The kernels are
-the TPU deployment path; both paths share the exact same semantics, which
-the per-kernel allclose sweeps in tests/test_kernels.py enforce.
+Model code reaches ``nm_spmm`` through ``core.operand.nm_apply``, whose
+``backend="auto"`` resolves to the kernel on TPU and to jnp elsewhere;
+the optimizer and the gradient sync take ``use_pallas=`` from their
+builders.  A shape no tiling suits gets whole-dimension blocks (always
+a legal Mosaic block), and one no block fits in VMEM raises — no
+dispatcher here swaps in the oracle behind the caller's back.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -25,16 +29,47 @@ from repro.kernels.grad_compress import (
     grad_compress_pallas,
     grad_decompress_mean_pallas,
 )
-from repro.kernels.nm_compact import nm_compact_pallas
+from repro.kernels.nm_compact import ROWS, nm_compact_pallas
 from repro.kernels.nm_spmm import nm_spmm_pallas
 from repro.kernels.nm_spmm_shared import nm_spmm_shared_pallas
 
-# VMEM budget used by the shared-mode act-panel residency check (bytes).
+# VMEM a kernel's blocks may take (bytes): under the 16 MiB Mosaic
+# scoped-VMEM default, with room for double buffering and scratch.
 _VMEM_BUDGET = 12 * 1024 * 1024
 
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _lane_width(shape, n: int, m: int, idx_bits: int = 8) -> int:
+    """Row width W that a lane-grouped kernel tiles a (..., K) array into.
+
+    M-groups run along the last axis and never cross a multiple of M in
+    the flattened array, so any W that M divides and that divides the
+    array's size re-rows it for free.  W is chosen so the packed
+    output block is lane-dense (a multiple of 128 lanes — the u4 plane
+    included, which also needs an even compact row so nibble pairs never
+    straddle two original rows); without one, W is K itself."""
+    k = shape[-1]
+    size = math.prod(shape)
+    for w in (1024, 512, 2048, 256, 4096, 128):
+        lanes = w // m * n // (2 if idx_bits == 4 else 1)
+        if (w % m == 0 and size % w == 0 and lanes % 128 == 0
+                and (idx_bits == 8 or (k // m * n) % 2 == 0)):
+            return w
+    return k
+
+
+def _as_rows(x: jax.Array, w: int) -> jax.Array:
+    """(..., K) -> (R, W), R zero-padded up to a multiple of ``ROWS``."""
+    x2 = x.reshape(-1, w)
+    pad = -x2.shape[0] % ROWS
+    return jnp.pad(x2, ((0, pad), (0, 0))) if pad else x2
+
+
+def _from_rows(y: jax.Array, shape) -> jax.Array:
+    return y.reshape(-1)[:math.prod(shape)].reshape(shape)
 
 
 @functools.partial(jax.jit,
@@ -45,28 +80,23 @@ def nm_compact(x: jax.Array, n: int, m: int, use_pallas: bool = True,
 
     ``idx_bits=4`` returns the u4 index plane (two offsets per byte,
     compact axis length ceil(Kc/2)); the Pallas path emits it straight
-    from the selection tile, the fallback packs the oracle's bytes.
+    from the selection tile, the oracle path packs the oracle's bytes.
     """
     if idx_bits not in (4, 8):
         raise ValueError(f"idx_bits must be 4 or 8, got {idx_bits}")
     shape = x.shape
     kc = shape[-1] // m * n
-    bk_ok = True
-    if use_pallas:
-        x2 = x.reshape(-1, shape[-1])
-        r, k = x2.shape
-        br = _pick_block(r, (256, 128, 64, 32, 16, 8, 4, 2, 1))
-        bk = _pick_block(k, (512, 256, 128, 64, 32, 16, 8), multiple_of=m)
-        bk_ok = idx_bits == 8 or (bk // m * n) % 2 == 0
-    if not use_pallas or not bk_ok:
+    kci = (kc + 1) // 2 if idx_bits == 4 else kc
+    if not use_pallas:
         v, i = ref.ref_nm_compact(x, n, m)
         if idx_bits == 4:
             i = S.pack_idx_u4(i, axis=-1)
         return v, i
-    v, i = nm_compact_pallas(x2, n, m, block_r=br, block_k=bk,
-                             idx_bits=idx_bits, interpret=_interpret())
-    kci = (kc + 1) // 2 if idx_bits == 4 else kc
-    return v.reshape(*shape[:-1], kc), i.reshape(*shape[:-1], kci)
+    w = _lane_width(shape, n, m, idx_bits)
+    v, i = nm_compact_pallas(_as_rows(x, w), n, m, idx_bits=idx_bits,
+                             interpret=_interpret())
+    return (_from_rows(v, (*shape[:-1], kc)),
+            _from_rows(i, (*shape[:-1], kci)))
 
 
 @functools.partial(jax.jit,
@@ -79,22 +109,21 @@ def nm_spmm(act, vals, idx, n: int, m: int, use_pallas: bool = True,
     in-group offsets per byte, low nibble first (see
     ``core.sparsity.pack_idx_u4``).  The Pallas path fuses the nibble
     expansion into the tile decompress (half the index HBM traffic, no
-    dense weight outside VMEM); shapes the tiled kernel cannot split
-    evenly (odd compact tiles — impossible for even n) fall back to the
-    oracle.  Both paths are bitwise-identical to ``idx_bits=8`` on the
-    same offsets.
+    dense weight outside VMEM).  Both widths are bitwise identical to
+    each other and to the oracle on the same offsets.
     """
     if not use_pallas:
         return ref.ref_nm_spmm(act, vals, idx, n, m, idx_bits=idx_bits)
     b, k = act.shape
     kc, f = vals.shape
-    bb = _pick_block(b, (128, 64, 32, 16, 8, 4, 2, 1))
-    bf = _pick_block(f, (128, 64, 32, 16, 8))
-    bk = _pick_block(k, (512, 256, 128, 64, 32, 16, 8), multiple_of=m)
-    if idx_bits == 4 and (kc % 2 or (bk // m * n) % 2):
-        # the tiled kernel streams whole bytes of the u4 plane; an odd
-        # compact tile would straddle one — route to the fused-free oracle
-        return ref.ref_nm_spmm(act, vals, idx, n, m, idx_bits=idx_bits)
+    # index rows per K tile: u8 tiles hold 32 rows, and a u4 byte must
+    # not straddle two tiles
+    quantum = 64 if idx_bits == 4 else 32
+    bk = _pick_block(k, (512, 1024, 256, 2048),
+                     ok=lambda c: c % m == 0 and (c // m * n) % quantum == 0)
+    bf = _pick_block(f, (128,))
+    bb = _pick_block(b, (256, 128, 64, 32, 16, 8),
+                     ok=lambda c: c * bk * act.dtype.itemsize <= _VMEM_BUDGET // 4)
     return nm_spmm_pallas(
         act, vals, idx, n, m, block_b=bb, block_f=bf, block_k=bk,
         idx_bits=idx_bits, interpret=_interpret(),
@@ -104,12 +133,17 @@ def nm_spmm(act, vals, idx, n: int, m: int, use_pallas: bool = True,
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
 def nm_spmm_shared(act, vals, rows, use_pallas: bool = True):
     """Shared-pattern reduced-K matmul: true N/M FLOP saving on the MXU."""
-    b, k = act.shape
-    bb = _pick_block(b, (128, 64, 32, 16, 8, 4, 2, 1))
-    panel_bytes = bb * k * act.dtype.itemsize
-    if not use_pallas or panel_bytes > _VMEM_BUDGET:
+    if not use_pallas:
         return ref.ref_nm_spmm_shared(act, vals, rows)
-    return nm_spmm_shared_pallas(act, vals, rows, block_b=bb, interpret=_interpret())
+    b, k = act.shape
+    panel = k * 4  # the kernel holds the (K, TB) panel in f32
+    bb = _pick_block(b, (128, 256), ok=lambda c: c * panel <= _VMEM_BUDGET)
+    if bb * panel > _VMEM_BUDGET:
+        raise ValueError(
+            f"nm_spmm_shared: a ({k}, {bb}) activation panel exceeds the "
+            f"{_VMEM_BUDGET}-byte VMEM budget")
+    return nm_spmm_shared_pallas(act, vals, rows, block_b=bb,
+                                 interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("n", "m", "use_pallas"))
@@ -118,23 +152,15 @@ def fused_update(w, g, v, lr, mu, wd, lam, n: int, m: int, use_pallas: bool = Tr
     if not use_pallas:
         return ref.ref_fused_update(w, g, v, lr=lr, mu=mu, wd=wd, lam=lam, n=n, m=m)
     shape = w.shape
-    w2 = w.reshape(-1, shape[-1])
-    g2 = g.reshape(-1, shape[-1]).astype(jnp.float32)
-    v2 = v.reshape(-1, shape[-1])
-    r, k = w2.shape
-    br = _pick_block(r, (256, 128, 64, 32, 16, 8, 4, 2, 1))
-    bk = _pick_block(k, (512, 256, 128, 64, 32, 16, 8), multiple_of=m)
+    kc = shape[-1] // m * n
+    rw = _lane_width(shape, n, m)
     nw, nv, vals, idx = fused_update_pallas(
-        w2, g2, v2, lr, mu, wd, lam, n, m, block_r=br, block_k=bk,
-        interpret=_interpret(),
+        _as_rows(w, rw), _as_rows(g.astype(jnp.float32), rw),
+        _as_rows(v, rw), lr, mu, wd, lam, n, m, interpret=_interpret(),
     )
-    kc = k // m * n
-    return (
-        nw.reshape(shape),
-        nv.reshape(shape),
-        vals.reshape(*shape[:-1], kc),
-        idx.reshape(*shape[:-1], kc),
-    )
+    packed = (*shape[:-1], kc)
+    return (_from_rows(nw, shape), _from_rows(nv, shape),
+            _from_rows(vals, packed), _from_rows(idx, packed))
 
 
 def _jnp_grad_compress(g, err, n: int, m: int):
@@ -183,7 +209,7 @@ def _jnp_grad_compress(g, err, n: int, m: int):
     survivor = jnp.zeros(gg.shape, bool)
     for i in sel:
         survivor = survivor | (offs == i[..., None])
-    rounded = gg.astype(jnp.bfloat16).astype(jnp.float32)
+    rounded = ref.bf16_round(gg)
     new_err = jnp.where(survivor, gg - rounded, gg).reshape(t.shape)
     kc = k // m * n
     return (vals.astype(jnp.bfloat16).reshape(*t.shape[:-1], kc),
@@ -218,21 +244,15 @@ def grad_compress(g, err, n: int, m: int, use_pallas: bool = True):
     if not use_pallas:
         return _jnp_grad_compress(g, err, n, m)
     shape = g.shape
-    g2 = g.reshape(-1, shape[-1]).astype(jnp.float32)
-    e2 = err.reshape(-1, shape[-1]).astype(jnp.float32)
-    r, k = g2.shape
-    br = _pick_block(r, (8, 4, 2, 1))
-    bk = _pick_block(k, (2048, 1024, 512, 256, 128, 64, 32, 16, 8),
-                     multiple_of=m)
+    kc = shape[-1] // m * n
+    rw = _lane_width(shape, n, m)
     vals, idx, new_err = grad_compress_pallas(
-        g2, e2, n, m, block_r=br, block_k=bk, interpret=_interpret()
+        _as_rows(g.astype(jnp.float32), rw),
+        _as_rows(err.astype(jnp.float32), rw), n, m, interpret=_interpret()
     )
-    kc = k // m * n
-    return (
-        vals.reshape(*shape[:-1], kc),
-        idx.reshape(*shape[:-1], kc),
-        new_err.reshape(shape),
-    )
+    packed = (*shape[:-1], kc)
+    return (_from_rows(vals, packed), _from_rows(idx, packed),
+            _from_rows(new_err, shape))
 
 
 @functools.partial(jax.jit, static_argnames=("n", "m", "use_pallas"))
@@ -241,12 +261,13 @@ def grad_decompress_mean(vals, idx, n: int, m: int, use_pallas: bool = True):
     if not use_pallas:
         return _jnp_grad_decompress_mean(vals, idx, n, m)
     p, kc = vals.shape
-    bc = _pick_block(kc, (2048, 1024, 512, 256, 128, 64, 32, 16, 8),
-                     multiple_of=n)
-    out = grad_decompress_mean_pallas(
-        vals, idx, n, m, block_c=bc, interpret=_interpret()
-    )
-    return out.reshape(kc // n * m)
+    # packed rows of Wc = W*n/m lanes expand to lane-dense W-wide rows
+    k = kc // n * m
+    wc = _lane_width((k,), n, m) // m * n
+    per_pod = jax.vmap(lambda a: _as_rows(a, wc))
+    out = grad_decompress_mean_pallas(per_pod(vals), per_pod(idx), n, m,
+                                      interpret=_interpret())
+    return _from_rows(out, (k,))
 
 
 def pack_shared(w: jax.Array, n: int, m: int, tile: int = 128):
@@ -286,8 +307,11 @@ def packed_bytes(k: int, f: int, n: int, m: int, dtype_bytes: int = 2,
     return kc * f * dtype_bytes + kc * f * idx_bits // 8
 
 
-def _pick_block(dim: int, candidates, multiple_of: int = 1) -> int:
+def _pick_block(dim: int, candidates, ok=lambda c: True) -> int:
+    """First candidate that divides ``dim`` and passes ``ok``; else the
+    whole dimension (a block equal to the array dim is always a legal
+    Mosaic block, whatever the (8, 128) tiling)."""
     for c in candidates:
-        if c % multiple_of == 0 and dim % c == 0 and c <= dim:
+        if c <= dim and dim % c == 0 and ok(c):
             return c
     return dim

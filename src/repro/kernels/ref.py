@@ -52,6 +52,27 @@ def ref_nm_spmm_shared(act: jax.Array, vals: jax.Array, rows: jax.Array):
     return outs.reshape(act.shape[0], -1)
 
 
+def bf16_round(x: jax.Array) -> jax.Array:
+    """f32 -> the nearest bf16 value (ties to even), kept in f32.
+
+    Bitwise equal to ``x.astype(bfloat16).astype(float32)`` on every
+    finite x and on +-inf; finite values past bf16's largest round to
+    +-inf, as that convert does.  NaN stays NaN, sign kept, quiet bit
+    set.  Spelled in integer ops on purpose: a compiler may drop an
+    f32 -> bf16 -> f32 convert pair as excess precision (XLA on TPU
+    does), and that would erase exactly the rounding error the
+    error-feedback residual must carry.  The compress kernels and the
+    sync's reference semantics (``optim.compress.compress_leaf``) round
+    through this one helper.
+    """
+    u32 = jnp.uint32
+    b = jax.lax.bitcast_convert_type(x, u32)
+    rounded = (b + (u32(0x7FFF) + ((b >> u32(16)) & u32(1)))) & u32(0xFFFF0000)
+    quiet_nan = (b | u32(0x00400000)) & u32(0xFFFF0000)
+    return jax.lax.bitcast_convert_type(jnp.where(x != x, quiet_nan, rounded),
+                                        jnp.float32)
+
+
 def ref_grad_compress(g: jax.Array, err: jax.Array, n: int, m: int):
     """EF compress oracle: (g, err) -> (bf16 vals, uint8 idx, new_err f32).
 
@@ -62,9 +83,8 @@ def ref_grad_compress(g: jax.Array, err: jax.Array, n: int, m: int):
     """
     t = (g.astype(jnp.float32) + err.astype(jnp.float32))
     vals, idx = S.nm_pack(t, n, m, axis=-1)
-    sent = vals.astype(jnp.bfloat16)
-    dec = S.nm_unpack_n(sent.astype(jnp.float32), idx, n, m, axis=-1)
-    return sent, idx, t - dec
+    dec = S.nm_unpack_n(bf16_round(vals), idx, n, m, axis=-1)
+    return vals.astype(jnp.bfloat16), idx, t - dec
 
 
 def ref_grad_decompress_mean(vals: jax.Array, idx: jax.Array, n: int, m: int):

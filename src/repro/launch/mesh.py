@@ -15,12 +15,21 @@ once-per-step gradient reduction (optionally N:M-compressed) rides it.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the train and serve
+    steps are written for GSPMD propagation (sharding constraints, not
+    typed ``out_sharding=`` on every gather), which ``Explicit`` axes —
+    the default of ``jax.make_mesh`` since JAX 0.7 — would refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(*, model: int = 1, pods: int = 1):
@@ -34,9 +43,9 @@ def make_host_mesh(*, model: int = 1, pods: int = 1):
     if n % (model * pods):
         model = pods = 1
     if pods > 1:
-        return jax.make_mesh((pods, n // (model * pods), model),
-                             ("pod", "data", "model"))
-    return jax.make_mesh((n // model, model), ("data", "model"))
+        return _auto_mesh((pods, n // (model * pods), model),
+                          ("pod", "data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def mesh_chips(mesh) -> int:

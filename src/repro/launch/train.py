@@ -55,7 +55,7 @@ def build_parser():
                     help="gradient sparsifier for --compress: topk with "
                          "error feedback, or the unbiased MVUE sampler "
                          "(arXiv 2203.10991)")
-    ap.add_argument("--bucket-elems", type=int, default=1 << 16,
+    ap.add_argument("--bucket-elems", type=int, default=1 << 20,
                     help="compressed-sync bucket size in elements "
                          "(must be a multiple of M)")
     ap.add_argument("--model-parallel", type=int, default=1)
@@ -165,7 +165,12 @@ def run_training(args) -> int:
 
 
 def run_watchdog(args, argv) -> int:
-    """Supervise: restart-on-stale-heartbeat until steps complete."""
+    """Supervise: restart-on-stale-heartbeat until steps complete.
+
+    The supervisor never touches a JAX backend (no device query, no
+    array, no compile — importing jax initializes nothing): a chip
+    belongs to one process, and the child it starts must be able to
+    claim it."""
     assert args.ckpt_dir, "--watchdog requires --ckpt-dir"
     hb_path = os.path.join(args.ckpt_dir, "heartbeat.json")
     child_argv = [a for a in argv if a != "--watchdog"] + ["--resume"]
@@ -195,6 +200,9 @@ def run_watchdog(args, argv) -> int:
 def main(argv=None):
     argv = sys.argv[1:] if argv is None else argv
     args = build_parser().parse_args(argv)
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     if args.watchdog:
         sys.exit(run_watchdog(args, argv))
     sys.exit(run_training(args))

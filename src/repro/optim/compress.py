@@ -27,23 +27,24 @@ buckets inside a MANUAL shard_map — the compress math (fused
 kernels/grad_compress, no dense intermediates) is purely local so the
 GSPMD partitioner can never reshard inside it, and each bucket ends in
 one explicit packed (vals, idx) collective over "pod": the only traffic
-that crosses pods.  The payload ships vals bitcast to uint16 — XLA
+that crosses pods.  The walk is one ``lax.scan`` over equal buckets, so
+the program holds a single compress + exchange + decode body whatever
+the slab size: neither compile time nor the sync's scratch memory grows
+with the bucket count.  The payload ships vals bitcast to uint16 — XLA
 would otherwise hoist the decoder's bf16→f32 convert above the
 collective and double the wire bytes.  For the topk estimator on a
 two-pod mesh the hop is a ppermute *exchange* rather than an
 all_gather: error feedback already gives each pod its own decoded
 payload for free (decode(own) == (g+err) - new_err bit-for-bit, the
 bf16 rounding being Sterbenz-exact in f32), so only the peer's row pays
-the one-hot decode.  Buckets are independent ops with no barrier
-between them, so XLA's scheduler is free to overlap one bucket's
-collective with the next bucket's compression (and with trailing
-backward work under jit).
+the one-hot decode.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -56,27 +57,27 @@ from repro.core.sparsity import (
     nm_pack_from_mask,
 )
 from repro.kernels import ops
+from repro.kernels.ref import bf16_round
 from repro.sharding import rules as R
 
 
-def compress_leaf(g, err, n: int, m: int, wire_dtype=jnp.bfloat16):
+def compress_leaf(g, err, n: int, m: int):
     """N:M-sparsify g+err along the last axis; returns (sparse, new_err).
 
     The returned sparse tensor holds what the wire ACTUALLY carries —
-    the kept values rounded to ``wire_dtype`` (the packed all-gather
-    transmits bf16) — and the residual absorbs both the pruned values
-    AND that rounding error, so sum(sent) + err telescopes to sum(g)
-    exactly in fp32 (pinned by tests/test_spmd.py).  This is the
-    single-leaf reference semantics; the bucketed sync path below uses
-    the fused kernel equivalent (kernels/grad_compress).
+    the kept values rounded to bf16 (the packed exchange transmits
+    bf16) — and the residual absorbs both the pruned values AND that
+    rounding error, so sum(sent) + err telescopes to sum(g) exactly in
+    fp32 (pinned by tests/test_spmd.py).  This is the single-leaf
+    reference semantics; the bucketed sync path below uses the fused
+    kernel equivalent (kernels/grad_compress).
     """
     size = g.size
     if size % m != 0 or g.ndim == 0:
         return g, err  # tiny/ragged leaves ride uncompressed
     flat = (g + err).reshape(-1, m)
     mask = nm_mask(flat, n, m, axis=-1)
-    kept = jnp.where(mask, flat, 0.0)
-    sent = kept.astype(wire_dtype).astype(jnp.float32)
+    sent = bf16_round(jnp.where(mask, flat, 0.0).astype(jnp.float32))
     new_err = (flat - sent).reshape(g.shape)
     return sent.reshape(g.shape), new_err
 
@@ -93,13 +94,17 @@ class GradCompressConfig:
     bucket_elems must be a multiple of m: a bucket boundary inside an
     M-group would split the group's top-N selection across two buckets
     (and two collectives), silently changing the estimator — refused at
-    construction, and again by ``plan_buckets`` for ad-hoc splits.
+    construction, and again by ``plan_buckets`` for ad-hoc splits.  A
+    bucket is one scan iteration: its width sets the sync's scratch
+    memory (~100 B per element, by a compile for TPU v5e), the slab's
+    size sets the trip count (1 Mi elements: ~130 trips for a device's
+    share of the 4-layer granite-moe-1b-a400m step on pod=2,model=2).
     """
 
     n: int = 2
     m: int = 8
     estimator: str = "topk"       # "topk" (EF) | "mvue" (unbiased, no EF)
-    bucket_elems: int = 1 << 16
+    bucket_elems: int = 1 << 20
     use_pallas: bool = False
 
     def __post_init__(self):
@@ -291,12 +296,9 @@ def cross_pod_sync(grads, err, mesh: Mesh, grad_pspecs,
     that device's own shard of the pod-mean gradient — there is no
     global slab to assemble, no leaf re-replication before compressing,
     and no redistribution collective afterwards.  Ragged leaves ride a
-    dense fp32 pmean over "pod".  Buckets are independent ops with no
-    barrier, so the scheduler can overlap one bucket's gather with the
-    next bucket's compression (and with trailing backward work).
+    dense fp32 pmean over "pod".  The buckets are the iterations of one
+    scan; the last is zero-padded to full width.
     """
-    from jax.experimental.shard_map import shard_map
-
     n_pods = mesh.shape["pod"]
     n, m = cfg.n, cfg.m
     shards = slab_shards(mesh)
@@ -315,7 +317,45 @@ def cross_pod_sync(grads, err, mesh: Mesh, grad_pspecs,
             "state against the same master tree/specs/mesh")
     if key is None:
         key = jax.random.PRNGKey(0)
-    buckets = plan_buckets(t_loc_pad, cfg.bucket_elems, m)
+    # equal buckets, none wider than the slab, walked by one scan body
+    bucket = min(cfg.bucket_elems, t_loc_pad)
+    n_buckets = len(plan_buckets(t_loc_pad, bucket, m)) if t_loc_pad else 0
+
+    def sync_bucket(k, _, xs):
+        b, gb, ebk = xs
+        if cfg.estimator == "mvue":
+            vals, idx = mvue_compress(gb, n, m, jax.random.fold_in(k, b))
+            new_eb = ebk  # unbiased estimator: no residual
+        else:
+            vals, idx, new_eb = ops.grad_compress(
+                gb, ebk, n, m, use_pallas=cfg.use_pallas)
+        # ship vals bitcast to u16: XLA otherwise hoists the decoder's
+        # bf16->f32 convert above the collective and doubles the wire
+        # bytes of the hop
+        wire = jax.lax.bitcast_convert_type(vals, jnp.uint16)
+        if cfg.estimator == "topk" and n_pods == 2:
+            # EF telescoping gives the own pod's decoded payload for
+            # free — decode(own) == t - new_err bitwise (the bf16
+            # rounding error is Sterbenz-exact in f32) — so the pod hop
+            # is a payload *exchange* (ppermute) and only the peer's row
+            # pays the one-hot decode.
+            swap = [(0, 1), (1, 0)]
+            ov = jax.lax.bitcast_convert_type(
+                jax.lax.ppermute(wire, "pod", swap), jnp.bfloat16)
+            oi = jax.lax.ppermute(idx, "pod", swap)
+            own = (gb + ebk - new_eb)[0]
+            other = ops.grad_decompress_mean(ov, oi, n, m,
+                                             use_pallas=cfg.use_pallas)
+            out = (own + other) * 0.5
+        else:
+            # the pod hop: bf16 vals + u8 idx, N/M of dense bytes
+            vals = jax.lax.bitcast_convert_type(
+                jax.lax.all_gather(wire, "pod", axis=0, tiled=True),
+                jnp.bfloat16)
+            idx = jax.lax.all_gather(idx, "pod", axis=0, tiled=True)
+            out = ops.grad_decompress_mean(vals, idx, n, m,
+                                           use_pallas=cfg.use_pallas)
+        return None, (out, new_eb)
 
     def sync_shard(*args):
         flat_loc, eb, k = args[:-2], args[-2], args[-1]
@@ -326,51 +366,18 @@ def cross_pod_sync(grads, err, mesh: Mesh, grad_pspecs,
             k = jax.random.fold_in(k, jax.lax.axis_index("pod"))
         blocks = [x.reshape(1, -1).astype(jnp.float32)
                   for x, c in zip(flat_loc, comp) if c]
-        outs, errs = [], []
-        if buckets:
-            loc = jnp.concatenate(blocks, axis=1)
-            if t_loc_pad != t_loc:  # zero pad: zero payload + zero err
-                loc = jnp.pad(loc, ((0, 0), (0, t_loc_pad - t_loc)))
-            for b, (s, e) in enumerate(buckets):
-                gb, ebk = loc[:, s:e], eb[:, s:e]
-                if cfg.estimator == "mvue":
-                    vals, idx = mvue_compress(gb, n, m,
-                                              jax.random.fold_in(k, b))
-                    new_eb = ebk  # unbiased estimator: no residual
-                else:
-                    vals, idx, new_eb = ops.grad_compress(
-                        gb, ebk, n, m, use_pallas=cfg.use_pallas)
-                # ship vals bitcast to u16: XLA otherwise hoists the
-                # decoder's bf16->f32 convert above the collective and
-                # doubles the wire bytes of the hop
-                wire = jax.lax.bitcast_convert_type(vals, jnp.uint16)
-                if cfg.estimator == "topk" and n_pods == 2:
-                    # EF telescoping gives the own pod's decoded payload
-                    # for free — decode(own) == t - new_err bitwise (the
-                    # bf16 rounding error is Sterbenz-exact in f32) — so
-                    # the pod hop is a payload *exchange* (ppermute) and
-                    # only the peer's row pays the one-hot decode.
-                    swap = [(0, 1), (1, 0)]
-                    ov = jax.lax.bitcast_convert_type(
-                        jax.lax.ppermute(wire, "pod", swap), jnp.bfloat16)
-                    oi = jax.lax.ppermute(idx, "pod", swap)
-                    own = (gb + ebk - new_eb)[0]
-                    other = ops.grad_decompress_mean(
-                        ov, oi, n, m, use_pallas=cfg.use_pallas)
-                    outs.append((own + other) * 0.5)
-                else:
-                    # the pod hop: bf16 vals + u8 idx, N/M of dense bytes
-                    vals = jax.lax.bitcast_convert_type(
-                        jax.lax.all_gather(wire, "pod", axis=0,
-                                           tiled=True), jnp.bfloat16)
-                    idx = jax.lax.all_gather(
-                        idx, "pod", axis=0, tiled=True)
-                    outs.append(ops.grad_decompress_mean(
-                        vals, idx, n, m, use_pallas=cfg.use_pallas))
-                errs.append(new_eb)
-        dense_loc = (jnp.concatenate(outs) if outs
-                     else jnp.zeros((0,), jnp.float32))
-        new_eb = jnp.concatenate(errs, axis=1) if errs else eb
+        dense_loc, new_eb = jnp.zeros((0,), jnp.float32), eb
+        if n_buckets:
+            # zero pad to whole buckets: zero payload and zero residual
+            loc = jnp.pad(jnp.concatenate(blocks, axis=1),
+                          ((0, 0), (0, n_buckets * bucket - t_loc)))
+            ebp = jnp.pad(eb, ((0, 0), (0, n_buckets * bucket - t_loc_pad)))
+            as_buckets = lambda x: x.reshape(n_buckets, 1, bucket)
+            _, (outs, errs) = jax.lax.scan(
+                partial(sync_bucket, k), None,
+                (jnp.arange(n_buckets), as_buckets(loc), as_buckets(ebp)))
+            dense_loc = outs.reshape(-1)[:t_loc]
+            new_eb = errs.reshape(1, -1)[:, :t_loc_pad]
         out, off = [], 0
         for x, c in zip(flat_loc, comp):
             if c:  # unconcat straight back into this device's block
@@ -381,11 +388,11 @@ def cross_pod_sync(grads, err, mesh: Mesh, grad_pspecs,
             out.append(leaf.astype(x.dtype))
         return (*out, new_eb)
 
-    res = shard_map(
+    res = jax.shard_map(
         sync_shard, mesh=mesh,
         in_specs=(*(P("pod", *s) for s in flat_s), err_spec, P()),
         out_specs=(*(P(*s) for s in flat_s), err_spec),
-        check_rep=False)(*flat_g, err, key)
+        check_vma=False)(*flat_g, err, key)
     return jax.tree_util.tree_unflatten(tdef, list(res[:-1])), res[-1]
 
 

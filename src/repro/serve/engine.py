@@ -81,10 +81,12 @@ class ServeEngine:
                                                idx_bits=serve_cfg.idx_bits)
             params = self.store.params
         shardings = None
-        if mesh is not None and mesh.devices.size > 1:
+        if mesh is not None:
             # SPMD serving: resolve SERVE_BATCH-rule shardings (weights
             # TP over "model" with N:M groups unsplit, slot lanes over
-            # the DP axes) and pin the engine's residents to them.
+            # the DP axes) and pin the engine's residents to them — on
+            # a one-device mesh this is what places a fleet replica on
+            # its own chip.
             from repro.launch import spmd
             shardings = spmd.serve_shardings(
                 cfg, mesh, sp_cfg, n_slots=serve_cfg.n_slots,

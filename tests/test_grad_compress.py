@@ -125,6 +125,39 @@ class TestTelescoping:
         np.testing.assert_array_equal(np.asarray(d), np.asarray(jd))
 
 
+class TestBf16Round:
+    """``ref.bf16_round`` — the one rounding the oracle, the jnp path and
+    the kernels share — is the plain f32 -> bf16 -> f32 convert."""
+
+    @settings(max_examples=50, deadline=None)
+    @given(bits=st.lists(st.integers(0, 2**32 - 1), min_size=1,
+                         max_size=64))
+    def test_bitwise_equal_to_convert_on_finite(self, bits):
+        x = np.asarray(bits, np.uint32).view(np.float32)
+        x = x[np.isfinite(x)]
+        want = np.asarray(jnp.asarray(x).astype(jnp.bfloat16)
+                          .astype(jnp.float32)).view(np.uint32)
+        got = np.asarray(ref.bf16_round(jnp.asarray(x))).view(np.uint32)
+        np.testing.assert_array_equal(got, want)
+
+    def test_ties_overflow_inf_and_nan(self):
+        one_ulp = 2.0 ** -7  # bf16 spacing at 1.0
+        big = float(jnp.finfo(jnp.bfloat16).max)
+        x = jnp.asarray([1 + one_ulp / 2, 1 + 1.5 * one_ulp,  # ties to even
+                         big * (1 + 3 * 2.0 ** -9), -np.inf, np.inf],
+                        jnp.float32)
+        np.testing.assert_array_equal(
+            np.asarray(ref.bf16_round(x)),
+            [1.0, 1 + 2 * one_ulp, np.inf, -np.inf, np.inf])
+        # NaN stays NaN with its sign, whatever its payload (an all-ones
+        # mantissa must not carry into the sign or exponent bits)
+        nans = np.asarray([0x7FFFFFFF, 0xFFFFFFFF, 0x7F800001, 0x7FC00000],
+                          np.uint32).view(np.float32)
+        got = np.asarray(ref.bf16_round(jnp.asarray(nans)))
+        assert np.isnan(got).all()
+        np.testing.assert_array_equal(np.signbit(got), np.signbit(nans))
+
+
 class TestTransposableMask:
     @settings(max_examples=20, deadline=None)
     @given(seed=st.integers(0, 2**16),

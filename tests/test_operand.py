@@ -617,15 +617,17 @@ class TestDeprecationShims:
         assert not bdwp.is_pregen(w)
 
     def test_shared_decompress_is_the_one_implementation(self):
-        """The dedicated helper is bitwise nm_unpack_n (scatter formul.)
-        and is what the kernel tile decompress delegates to."""
+        """The dedicated helper is bitwise nm_unpack_n (scatter formul.),
+        and so is the kernel's in-VMEM tile decompress (read out through
+        an identity activation)."""
         from repro.kernels import decompress_nm
-        from repro.kernels.nm_spmm import _decompress
+        from repro.kernels.ops import nm_spmm
 
         x, w, vals, idx, ff, bp = _pregen_arrays(23)
         _eq(decompress_nm(vals, idx, 2, 8, axis=-2),
             nm_unpack_n(vals, idx, 2, 8, axis=-2))
-        _eq(_decompress(vals, idx, 2, 8),
+        eye = jnp.eye(vals.shape[0] * 4, dtype=vals.dtype)
+        _eq(nm_spmm(eye, vals, idx, 2, 8).astype(vals.dtype),
             nm_unpack_n(vals, idx, 2, 8, axis=0))
         # stacked leaves decompress along the same axis, batched
         xs, ws, vs, is_, ffs, bps = _pregen_arrays(24, stack=(3,))

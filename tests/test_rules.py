@@ -3,7 +3,7 @@
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.launch.mesh import make_host_mesh
 from repro.sharding import rules as R
@@ -24,7 +24,8 @@ class TestSpecToPspec:
         assert ps == P(None, "model", "data", None)
 
     def test_divisibility_fallback(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         # fake a 16-way axis via rule check: use size-1 mesh -> divides
         ps = R.spec_to_pspec(("embed", "mlp"), R.TRAIN_RULES,
                              shape=(7, 13), mesh=mesh)

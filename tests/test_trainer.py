@@ -6,6 +6,7 @@ import os
 import time
 
 import jax
+from jax.sharding import AxisType
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -68,7 +69,7 @@ class TestCheckpoint:
         mgr = CheckpointManager(str(tmp_path))
         st = _state(5)
         mgr.save(5, st, blocking=True)
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
         sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), st)
         out = mgr.restore(_state(), shardings=sh)
         assert out["master"]["w"].sharding == NamedSharding(mesh, P())
@@ -365,3 +366,36 @@ class TestTrainerLoop:
         for a, b in zip(jax.tree.leaves(state2["master"]),
                         jax.tree.leaves(state3["master"])):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+class TestCompileCache:
+    """The persistent compile cache sits where JAX_COMPILATION_CACHE_DIR
+    says, else at one fixed path in the checkout; the cache key includes
+    the directory, so it must never move between runs."""
+
+    @pytest.fixture
+    def cache_dir(self):
+        old = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", old)
+
+    def test_env_variable_left_to_jax(self, cache_dir, monkeypatch, tmp_path):
+        from repro.launch.compile_cache import setup_compile_cache
+
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert setup_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    @pytest.mark.parametrize("env", [None, ""], ids=["unset", "empty"])
+    def test_fixed_checkout_path(self, cache_dir, monkeypatch, env):
+        from repro.launch.compile_cache import CHECKOUT, setup_compile_cache
+
+        if env is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+        first = setup_compile_cache()
+        assert first == str(CHECKOUT / ".jax_cache") == setup_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == first
+        assert (CHECKOUT / "chip_smoke.py").is_file()
