@@ -13,7 +13,7 @@ import time
 
 from benchmarks import (fig14_resources, fig15_speedup, fig16_layerwise,
                         fig17_scaling, fleet_bench, kernel_bench,
-                        pregen_bench, roofline, serve_bench, spmd_bench,
+                        pregen_bench, serve_bench, spmd_bench,
                         table2_flops, table4_platforms, table5_accels)
 
 SUITES = {
@@ -25,7 +25,6 @@ SUITES = {
     "fig17": fig17_scaling,
     "table5": table5_accels,
     "kernels": kernel_bench,
-    "roofline": roofline,
     "serve": serve_bench,
     # fleet layer above the engine: KV-aware routing + disaggregation
     "fleet": fleet_bench,

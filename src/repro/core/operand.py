@@ -61,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import scopes as S
 from repro.core.sparsity import SparsityConfig, sparsify
 
 __all__ = [
@@ -377,11 +378,13 @@ def _bp_weights(w: jax.Array, cfg: SparsityConfig) -> jax.Array:
 @partial(jax.custom_vjp, nondiff_argnums=(2,))
 def masked_linear(x: jax.Array, w: jax.Array, cfg: SparsityConfig):
     """y = x @ w with cfg.method's N:M sparse training semantics."""
-    return jnp.matmul(x, _ff_weights(w, cfg).astype(x.dtype))
+    with jax.named_scope(S.FF):
+        return jnp.matmul(x, _ff_weights(w, cfg).astype(x.dtype))
 
 
 def _masked_linear_fwd(x, w, cfg):
-    y = jnp.matmul(x, _ff_weights(w, cfg).astype(x.dtype))
+    with jax.named_scope(S.FF):
+        y = jnp.matmul(x, _ff_weights(w, cfg).astype(x.dtype))
     return y, (x, w)
 
 
@@ -393,17 +396,21 @@ def _masked_linear_bwd(cfg, res, g):
     # than the weights up — keeps backward activations, remat recompute
     # and the TP collectives in 16-bit (2x traffic saving, and faithful).
     gc = g.astype(x.dtype)
-    if cfg.prunes_bp_grads():  # SDGP: prune the *output gradients* N:M
-        g_bp = sparsify(gc, cfg, axis=-1)
-        dx = jnp.matmul(g_bp, w.T.astype(gc.dtype))
-    else:
-        w_bp = _bp_weights(w, cfg)
-        dx = jnp.matmul(gc, w_bp.T.astype(gc.dtype))
+    with jax.named_scope(S.BP):
+        if cfg.prunes_bp_grads():  # SDGP: prune the *output gradients* N:M
+            g_bp = sparsify(gc, cfg, axis=-1)
+            dx = jnp.matmul(g_bp, w.T.astype(gc.dtype))
+        else:
+            w_bp = _bp_weights(w, cfg)
+            dx = jnp.matmul(gc, w_bp.T.astype(gc.dtype))
+        dx = dx.reshape(x.shape).astype(x.dtype)
     # WU: dense (paper Alg. 1 line 9), straight-through; fp32 accumulation
-    x2 = x.reshape(-1, x.shape[-1])
-    g2 = gc.reshape(-1, gc.shape[-1])
-    dw = jnp.matmul(x2.T, g2, preferred_element_type=jnp.float32)
-    return dx.reshape(x.shape).astype(x.dtype), dw.astype(w.dtype)
+    with jax.named_scope(S.WU):
+        x2 = x.reshape(-1, x.shape[-1])
+        g2 = gc.reshape(-1, gc.shape[-1])
+        dw = jnp.matmul(x2.T, g2, preferred_element_type=jnp.float32)
+        dw = dw.astype(w.dtype)
+    return dx, dw
 
 
 masked_linear.defvjp(_masked_linear_fwd, _masked_linear_bwd)
@@ -413,22 +420,27 @@ masked_linear.defvjp(_masked_linear_fwd, _masked_linear_bwd)
 def pregen_linear(x: jax.Array, ff: jax.Array, bp: jax.Array) -> jax.Array:
     """y = x @ ff with BP on ``bp`` and the dense WU gradient riding the
     ``bp`` cotangent (always dense-shaped)."""
-    return jnp.matmul(x, ff.astype(x.dtype))
+    with jax.named_scope(S.FF):
+        return jnp.matmul(x, ff.astype(x.dtype))
 
 
 def _pregen_linear_fwd(x, ff, bp):
-    return jnp.matmul(x, ff.astype(x.dtype)), (x, ff, bp)
+    with jax.named_scope(S.FF):
+        return jnp.matmul(x, ff.astype(x.dtype)), (x, ff, bp)
 
 
 def _pregen_linear_bwd(res, g):
     x, ff, bp = res
     gc = g.astype(x.dtype)
-    dx = jnp.matmul(gc, bp.T.astype(gc.dtype))
-    x2 = x.reshape(-1, x.shape[-1])
-    g2 = gc.reshape(-1, gc.shape[-1])
-    dw = jnp.matmul(x2.T, g2, preferred_element_type=jnp.float32)
-    return (dx.reshape(x.shape).astype(x.dtype), jnp.zeros_like(ff),
-            dw.astype(bp.dtype))
+    with jax.named_scope(S.BP):
+        dx = jnp.matmul(gc, bp.T.astype(gc.dtype))
+        dx = dx.reshape(x.shape).astype(x.dtype)
+    with jax.named_scope(S.WU):
+        x2 = x.reshape(-1, x.shape[-1])
+        g2 = gc.reshape(-1, gc.shape[-1])
+        dw = jnp.matmul(x2.T, g2, preferred_element_type=jnp.float32)
+        dw = dw.astype(bp.dtype)
+    return dx, jnp.zeros_like(ff), dw
 
 
 pregen_linear.defvjp(_pregen_linear_fwd, _pregen_linear_bwd)
@@ -472,9 +484,10 @@ def packed_pregen_linear(x, vals, idx, bp, n: int, m: int,
 
 def _packed_pregen_fwd(x, vals, idx, bp, n, m, use_pallas, idx_bits=8):
     stack = vals.ndim - 2
-    x2 = x.reshape(*x.shape[:stack], -1, x.shape[-1])
-    y = _spmm_stacked(x2, vals, idx, n, m, use_pallas, idx_bits)
-    y = y.reshape(*x.shape[:-1], vals.shape[-1]).astype(x.dtype)
+    with jax.named_scope(S.FF):
+        x2 = x.reshape(*x.shape[:stack], -1, x.shape[-1])
+        y = _spmm_stacked(x2, vals, idx, n, m, use_pallas, idx_bits)
+        y = y.reshape(*x.shape[:-1], vals.shape[-1]).astype(x.dtype)
     return y, (x, vals, idx, bp)
 
 
@@ -486,13 +499,15 @@ def _packed_pregen_bwd(n, m, use_pallas, idx_bits, res, g):
     # (vmapped) pregen_linear backward
     g2 = gc.reshape(*gc.shape[:stack], -1, gc.shape[-1])
     x2 = x.reshape(*x.shape[:stack], -1, x.shape[-1])
-    bp_t = jnp.swapaxes(bp, -1, -2).astype(gc.dtype)
-    dx = jnp.matmul(g2, bp_t).reshape(x.shape).astype(x.dtype)
+    with jax.named_scope(S.BP):
+        bp_t = jnp.swapaxes(bp, -1, -2).astype(gc.dtype)
+        dx = jnp.matmul(g2, bp_t).reshape(x.shape).astype(x.dtype)
     # WU: dense straight-through, fp32-accumulated, on the bp cotangent
-    dw = jnp.matmul(jnp.swapaxes(x2, -1, -2), g2,
-                    preferred_element_type=jnp.float32)
+    with jax.named_scope(S.WU):
+        dw = jnp.matmul(jnp.swapaxes(x2, -1, -2), g2,
+                        preferred_element_type=jnp.float32).astype(bp.dtype)
     didx = np.zeros(idx.shape, dtype=jax.dtypes.float0)
-    return dx, jnp.zeros_like(vals), didx, dw.astype(bp.dtype)
+    return dx, jnp.zeros_like(vals), didx, dw
 
 
 packed_pregen_linear.defvjp(_packed_pregen_fwd, _packed_pregen_bwd)
@@ -523,13 +538,15 @@ def _packed_pregen_t_bwd(n, m, use_pallas, idx_bits, res, g):
     gc = g.astype(x.dtype)
     g2 = gc.reshape(*gc.shape[:stack], -1, gc.shape[-1])
     x2 = x.reshape(*x.shape[:stack], -1, x.shape[-1])
-    w_bp = decompress_nm(vals, idx, n, m, axis=-2, idx_bits=idx_bits)
-    dx = jnp.matmul(g2, jnp.swapaxes(w_bp, -1, -2).astype(gc.dtype))
-    dx = dx.reshape(x.shape).astype(x.dtype)
-    dw = jnp.matmul(jnp.swapaxes(x2, -1, -2), g2,
-                    preferred_element_type=jnp.float32)
+    with jax.named_scope(S.BP):
+        w_bp = decompress_nm(vals, idx, n, m, axis=-2, idx_bits=idx_bits)
+        dx = jnp.matmul(g2, jnp.swapaxes(w_bp, -1, -2).astype(gc.dtype))
+        dx = dx.reshape(x.shape).astype(x.dtype)
+    with jax.named_scope(S.WU):
+        dw = jnp.matmul(jnp.swapaxes(x2, -1, -2), g2,
+                        preferred_element_type=jnp.float32).astype(bp.dtype)
     didx = np.zeros(idx.shape, dtype=jax.dtypes.float0)
-    return dx, jnp.zeros_like(vals), didx, dw.astype(bp.dtype)
+    return dx, jnp.zeros_like(vals), didx, dw
 
 
 packed_pregen_linear_t.defvjp(_packed_pregen_fwd, _packed_pregen_t_bwd)
@@ -707,7 +724,8 @@ def nm_apply(op, x: jax.Array, *, backend: str = "auto",
                 else packed_pregen_linear
             return fn(x, op.vals, op.idx, op.bp, cfg.n, cfg.m, True,
                       op.idx_bits)
-        ff = _pregen_ff_dense(op)
+        with jax.named_scope(S.FF):  # decompressing a packed FF operand
+            ff = _pregen_ff_dense(op)
         if stacked:
             return jax.vmap(pregen_linear)(x, ff, op.bp)
         return pregen_linear(x, ff, op.bp)

@@ -62,6 +62,7 @@ _PAIRS_RE = re.compile(r"source_target_pairs=\{\{(.*?)\}\}")
 _CONTRACT_RE = re.compile(r"lhs_contracting_dims=\{([\d,]*)\}")
 _OPERAND_NAME_RE = re.compile(r"%([\w.\-]+)")
 _WINDOW_RE = re.compile(r"window=\{size=([\dx]+)")
+_OP_NAME_META_RE = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 
 _FREE_OPS = {
     "parameter", "constant", "tuple", "get-tuple-element", "bitcast",
@@ -98,6 +99,7 @@ class Op:
     type_text: str       # result type(s)
     operands: List[str]  # operand op names
     line: str
+    op_name: str = ""    # metadata op_name: the JAX scope path, or ''
 
 
 @dataclasses.dataclass
@@ -170,8 +172,24 @@ def _split_top_args(argstr: str) -> List[str]:
     return parts
 
 
-_OP_RE = re.compile(
-    r"^(\(?[a-z0-9\[\],{}\/ *#:]+?\)?)\s+([\w\-]+)\((.*)$")
+# one array type with its optional layout; TPU layouts carry tiling in
+# parentheses: bf16[8,1024]{1,0:T(8,128)(2,1)}
+_TYPE_RE = re.compile(r"[a-z0-9]+\[[^\]]*\](?:\{[^}]*\})?")
+_KIND_RE = re.compile(r"\s+([\w\-]+)\((.*)$")
+
+
+def _split_op(rest: str):
+    """(result type, op kind, text after the kind's open paren) of an
+    instruction's right-hand side, or None."""
+    if rest.startswith("("):   # tuple type: up to its matching paren
+        end = _balanced(rest, 0)
+    else:
+        t = _TYPE_RE.match(rest)
+        if not t:
+            return None
+        end = t.end()
+    m = _KIND_RE.match(rest, end)
+    return (rest[:end], m.group(1), m.group(2)) if m else None
 
 
 def _balanced(text: str, start: int) -> int:
@@ -221,10 +239,10 @@ def parse_module(text: str) -> Dict[str, Computation]:
             continue
         name, rest = d.group(1), d.group(2)
         is_root = line.lstrip().startswith("ROOT ")
-        m = _OP_RE.match(rest)
+        m = _split_op(rest)
         if not m:
             continue
-        type_text, kind, tail = m.groups()
+        type_text, kind, tail = m
         if is_root:
             cur.root = name
         # operand list = everything until the matching close paren
@@ -244,7 +262,9 @@ def parse_module(text: str) -> Dict[str, Computation]:
             names = _OPERAND_NAME_RE.findall(part)
             if names:
                 operands.append(names[-1])
-        op = Op(name, kind, type_text.strip(), operands, line)
+        meta = _OP_NAME_META_RE.search(line)
+        op = Op(name, kind, type_text.strip(), operands, line,
+                meta.group(1) if meta else "")
         cur.ops.append(op)
         cur.table[name] = op.type_text
     return comps

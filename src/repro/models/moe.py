@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from repro.core import bdwp
 from repro.core import operand as O
+from repro.core import scopes as SC
 from repro.core.sparsity import SparsityConfig
 from repro.models import layers as L
 from repro.sharding.rules import BATCH, act
@@ -131,53 +132,55 @@ def moe_apply(p, x, cfg: MoEConfig, sp_cfg: SparsityConfig):
     while t % sg:  # static: largest divisor fallback
         sg -= 1
     g = t // sg
-    xt = x.reshape(g, sg, d)
-
-    logits = jnp.matmul(xt, p["router"]["w"].astype(xt.dtype),
-                        preferred_element_type=jnp.float32)  # (G, S, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)  # (G, S, K)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
-
     cap = int(max(cfg.top_k, round(sg * cfg.capacity_factor * k / e)))
     cap = min(cap, sg)
 
-    # slot assignment inside each (group, expert) queue
-    onehot = jax.nn.one_hot(gate_idx, e, dtype=jnp.int32)  # (G, S, K, E)
-    flat = onehot.reshape(g, sg * k, e)
-    pos_in_e = jnp.cumsum(flat, axis=1) - flat              # (G, S*K, E)
-    pos = (pos_in_e * flat).sum(-1).reshape(g, sg, k)       # (G, S, K)
-    keep = pos < cap
-    gate_vals = gate_vals * keep
+    with jax.named_scope(SC.MOE_DISPATCH):
+        xt = x.reshape(g, sg, d)
+        logits = jnp.matmul(xt, p["router"]["w"].astype(xt.dtype),
+                            preferred_element_type=jnp.float32)  # (G, S, E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(probs, k)  # (G, S, K)
+        gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True),
+                                            1e-9)
 
-    # scatter: slot_token[g, e, c] = index of the token filling that slot
-    gi = jnp.broadcast_to(jnp.arange(g)[:, None, None], gate_idx.shape)
-    si = jnp.broadcast_to(jnp.arange(sg)[None, :, None], gate_idx.shape)
-    pos_c = jnp.where(keep, pos, cap)  # dropped -> sentinel column
-    slot_token = jnp.full((g, e, cap + 1), sg, jnp.int32)  # sg = zero row
-    slot_token = slot_token.at[gi, gate_idx, pos_c].set(si, mode="drop")
-    slot_token = slot_token[..., :cap]                      # (G, E, C)
+        # slot assignment inside each (group, expert) queue
+        onehot = jax.nn.one_hot(gate_idx, e, dtype=jnp.int32)  # (G, S, K, E)
+        flat = onehot.reshape(g, sg * k, e)
+        pos_in_e = jnp.cumsum(flat, axis=1) - flat              # (G, S*K, E)
+        pos = (pos_in_e * flat).sum(-1).reshape(g, sg, k)       # (G, S, K)
+        keep = pos < cap
+        gate_vals = gate_vals * keep
 
-    # gather dispatched tokens (sentinel index sg is OOB -> reads zero)
-    x_e = _slot_gather(xt, slot_token)                      # (G, E, C, d)
-    x_e = act(x_e, BATCH, "model", None, None)  # EP: experts over "model"
-    xe2 = x_e.transpose(1, 0, 2, 3).reshape(e, g * cap, d)  # the all-to-all
+        # scatter: slot_token[g, e, c] = index of the token filling that slot
+        gi = jnp.broadcast_to(jnp.arange(g)[:, None, None], gate_idx.shape)
+        si = jnp.broadcast_to(jnp.arange(sg)[None, :, None], gate_idx.shape)
+        pos_c = jnp.where(keep, pos, cap)  # dropped -> sentinel column
+        slot_token = jnp.full((g, e, cap + 1), sg, jnp.int32)  # sg = zero row
+        slot_token = slot_token.at[gi, gate_idx, pos_c].set(si, mode="drop")
+        slot_token = slot_token[..., :cap]                      # (G, E, C)
+
+        # gather dispatched tokens (sentinel index sg is OOB -> reads zero)
+        x_e = _slot_gather(xt, slot_token)                      # (G, E, C, d)
+        x_e = act(x_e, BATCH, "model", None, None)  # EP: experts over "model"
+        xe2 = x_e.transpose(1, 0, 2, 3).reshape(e, g * cap, d)  # the all-to-all
     y_e = _expert_ffn(p["w_gate"], p["w_up"], p["w_down"], xe2, sp_cfg)
-    y_e = y_e.reshape(e, g, cap, d).transpose(1, 0, 2, 3)   # (G, E, C, d)
-    y_e = act(y_e, BATCH, "model", None, None)
-    # reshard expert-sharded outputs back to token shards BEFORE the
-    # combine gather — one (G,E,C,d)-sized hop (a2a-class traffic);
-    # gathering from an expert-sharded tensor instead would all-gather
-    # the full dispatched tensor onto every chip (~16x the bytes)
-    y_e = act(y_e, BATCH, None, None, None)
+    with jax.named_scope(SC.MOE_DISPATCH):
+        y_e = y_e.reshape(e, g, cap, d).transpose(1, 0, 2, 3)   # (G, E, C, d)
+        y_e = act(y_e, BATCH, "model", None, None)
+        # reshard expert-sharded outputs back to token shards BEFORE the
+        # combine gather — one (G,E,C,d)-sized hop (a2a-class traffic);
+        # gathering from an expert-sharded tensor instead would all-gather
+        # the full dispatched tensor onto every chip (~16x the bytes)
+        y_e = act(y_e, BATCH, None, None, None)
 
-    # combine: token side gathers its K slots back, weighted by gates
-    y_flat = y_e.reshape(g, e * cap, d)
-    slot_of = gate_idx * cap + jnp.where(keep, pos, 0)      # (G, S, K)
-    y_k = _slot_gather(y_flat, slot_of)                     # (G, S, K, d)
-    yt = (y_k * gate_vals[..., None].astype(y_k.dtype)).sum(2)  # (G, S, d)
-    yt = act(yt, BATCH, None, None)
-    yt = yt.reshape(t, d)
+        # combine: token side gathers its K slots back, weighted by gates
+        y_flat = y_e.reshape(g, e * cap, d)
+        slot_of = gate_idx * cap + jnp.where(keep, pos, 0)      # (G, S, K)
+        y_k = _slot_gather(y_flat, slot_of)                     # (G, S, K, d)
+        yt = (y_k * gate_vals[..., None].astype(y_k.dtype)).sum(2)  # (G, S, d)
+        yt = act(yt, BATCH, None, None)
+        yt = yt.reshape(t, d)
 
     if "shared" in p:
         sh = p["shared"]
@@ -188,8 +191,9 @@ def moe_apply(p, x, cfg: MoEConfig, sp_cfg: SparsityConfig):
                          "moe/shared/w_down", sp_cfg)
 
     # Switch-style load-balance aux loss (counts from kept assignments)
-    me = probs.mean((0, 1))                                 # (E,)
-    counts = (onehot * keep[..., None]).sum((0, 1, 2)).astype(jnp.float32)
-    ce = counts / jnp.maximum(counts.sum(), 1.0)
-    aux = e * jnp.sum(me * ce)
+    with jax.named_scope(SC.MOE_DISPATCH):
+        me = probs.mean((0, 1))                                 # (E,)
+        counts = (onehot * keep[..., None]).sum((0, 1, 2)).astype(jnp.float32)
+        ce = counts / jnp.maximum(counts.sum(), 1.0)
+        aux = e * jnp.sum(me * ce)
     return yt.reshape(b, s, d).astype(x.dtype), aux

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import bdwp
+from repro.core import scopes as SC
 from repro.core.sparsity import DENSE, SparsityConfig
 from repro.models import attention as A
 from repro.models import layers as L
@@ -181,10 +182,11 @@ def _block_apply(p, x, cfg: LMConfig, sp_cfg, *, positions, is_global,
             if cache is not None else None
         s_cache = {k: v for k, v in cache.items() if k in ("state", "conv")} \
             if cache is not None else None
-        a_out, a_nc = A.attn_apply(p["attn"], h, acfg, sp_cfg,
-                                   positions=positions, cache=a_cache,
-                                   layer_window=cfg.window, decode=decode,
-                                   per_slot=per_slot)
+        with jax.named_scope(SC.ATTENTION):
+            a_out, a_nc = A.attn_apply(p["attn"], h, acfg, sp_cfg,
+                                       positions=positions, cache=a_cache,
+                                       layer_window=cfg.window, decode=decode,
+                                       per_slot=per_slot)
         s_out, s_nc = S.ssm_apply(p["ssm"], h, cfg.ssm_cfg(), sp_cfg,
                                   cache=s_cache, decode=decode)
         mix = 0.5 * (a_out + s_out)  # hymba: parallel heads, mean-combined
@@ -207,13 +209,16 @@ def _block_apply(p, x, cfg: LMConfig, sp_cfg, *, positions, is_global,
                                     layer_window=cfg.window, decode=decode,
                                     per_slot=per_slot)
 
-            mix, nc = jax.lax.cond(is_global, global_branch, local_branch, h)
+            with jax.named_scope(SC.ATTENTION):
+                mix, nc = jax.lax.cond(is_global, global_branch,
+                                       local_branch, h)
         else:
             window = cfg.window if kinds[0] == "swa" else None
-            mix, nc = A.attn_apply(p["attn"], h, acfg, sp_cfg,
-                                   positions=positions, cache=cache,
-                                   layer_window=window, decode=decode,
-                                   per_slot=per_slot)
+            with jax.named_scope(SC.ATTENTION):
+                mix, nc = A.attn_apply(p["attn"], h, acfg, sp_cfg,
+                                       positions=positions, cache=cache,
+                                       layer_window=window, decode=decode,
+                                       per_slot=per_slot)
         if nc is not None:
             new_cache = nc
     x = x + mix
@@ -310,10 +315,11 @@ def forward(params, tokens, cfg: LMConfig, sp_cfg: SparsityConfig = DENSE, *,
     ``positions`` instead of the shared ``cache["pos"]`` cursor (the
     serve engine's continuous-batching mode).
     """
-    x = L.embed_apply(params["embed"], tokens)
-    if prefix_embeds is not None:
-        x = jnp.concatenate([prefix_embeds.astype(x.dtype), x], axis=1)
-    x = act(x, BATCH, SEQ, None)
+    with jax.named_scope(SC.EMBED_HEAD):
+        x = L.embed_apply(params["embed"], tokens)
+        if prefix_embeds is not None:
+            x = jnp.concatenate([prefix_embeds.astype(x.dtype), x], axis=1)
+        x = act(x, BATCH, SEQ, None)
     b, s_tot = x.shape[0], x.shape[1]
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(s_tot), (b, s_tot))
@@ -322,12 +328,16 @@ def forward(params, tokens, cfg: LMConfig, sp_cfg: SparsityConfig = DENSE, *,
     if cfg.uses_scan_prelude:
         pre = params["prelude"]
         pc = cache["prelude"] if cache is not None else None
-        h = L.rmsnorm_apply(pre["ln1"], x)
-        mix, pre_nc = A.attn_apply(pre["attn"], h, cfg.attn_cfg(), sp_cfg,
-                                   positions=positions, cache=pc, decode=decode,
-                                   per_slot=per_slot)
-        x = x + mix
-        x = x + ffn_apply(pre["ffn"], L.rmsnorm_apply(pre["ln2"], x), sp_cfg)
+        with jax.named_scope(SC.BLOCKS):
+            h = L.rmsnorm_apply(pre["ln1"], x)
+            with jax.named_scope(SC.ATTENTION):
+                mix, pre_nc = A.attn_apply(pre["attn"], h, cfg.attn_cfg(),
+                                           sp_cfg, positions=positions,
+                                           cache=pc, decode=decode,
+                                           per_slot=per_slot)
+            x = x + mix
+            x = x + ffn_apply(pre["ffn"], L.rmsnorm_apply(pre["ln2"], x),
+                              sp_cfg)
     else:
         pre_nc = None
 
@@ -346,20 +356,22 @@ def forward(params, tokens, cfg: LMConfig, sp_cfg: SparsityConfig = DENSE, *,
         return (xh, aux + a), nc
 
     layer_caches = cache["layers"] if cache is not None else None
-    if layer_caches is None:
-        (x, aux_total), _ = jax.lax.scan(
-            lambda c, xs: _strip_cache(body(c, (*xs, None))),
-            (x, aux_total), (params["blocks"], flags))
-        new_cache = None
-    else:
-        (x, aux_total), new_layer_caches = jax.lax.scan(
-            body, (x, aux_total), (params["blocks"], flags, layer_caches))
-        new_cache = {"layers": new_layer_caches}
-        if pre_nc is not None:
-            new_cache["prelude"] = pre_nc
+    with jax.named_scope(SC.BLOCKS):
+        if layer_caches is None:
+            (x, aux_total), _ = jax.lax.scan(
+                lambda c, xs: _strip_cache(body(c, (*xs, None))),
+                (x, aux_total), (params["blocks"], flags))
+            new_cache = None
+        else:
+            (x, aux_total), new_layer_caches = jax.lax.scan(
+                body, (x, aux_total), (params["blocks"], flags, layer_caches))
+            new_cache = {"layers": new_layer_caches}
+            if pre_nc is not None:
+                new_cache["prelude"] = pre_nc
 
-    x = act(x, BATCH, SEQ, None)
-    x = L.rmsnorm_apply(params["final_norm"], x)
+    with jax.named_scope(SC.EMBED_HEAD):
+        x = act(x, BATCH, SEQ, None)
+        x = L.rmsnorm_apply(params["final_norm"], x)
     return x, new_cache, aux_total
 
 
@@ -370,11 +382,12 @@ def _strip_cache(res):
 
 def logits_from_hidden(params, hidden, cfg: LMConfig):
     table = params["embed"]["embed_table"] if cfg.tie_embed else params["lm_head"]["w"].T
-    logits = jnp.matmul(hidden, table.T.astype(hidden.dtype),
-                        preferred_element_type=jnp.float32)
-    if cfg.padded_vocab != cfg.vocab:  # mask padded columns (static)
-        valid = jnp.arange(cfg.padded_vocab) < cfg.vocab
-        logits = jnp.where(valid, logits, -1e30)
+    with jax.named_scope(SC.EMBED_HEAD):
+        logits = jnp.matmul(hidden, table.T.astype(hidden.dtype),
+                            preferred_element_type=jnp.float32)
+        if cfg.padded_vocab != cfg.vocab:  # mask padded columns (static)
+            valid = jnp.arange(cfg.padded_vocab) < cfg.vocab
+            logits = jnp.where(valid, logits, -1e30)
     return logits
 
 
@@ -385,12 +398,6 @@ def lm_loss(params, hidden, labels, cfg: LMConfig, *, chunk: int = 1024,
     chunk = min(chunk, s)
     assert s % chunk == 0
     nc = s // chunk
-    hs = hidden.reshape(b, nc, chunk, d).swapaxes(0, 1)
-    ls = labels.reshape(b, nc, chunk).swapaxes(0, 1)
-    if mask is None:
-        ms = jnp.ones((nc, b, chunk), jnp.float32)
-    else:
-        ms = mask.reshape(b, nc, chunk).swapaxes(0, 1).astype(jnp.float32)
 
     def step(acc, xs):
         h, l, mk = xs
@@ -401,8 +408,16 @@ def lm_loss(params, hidden, labels, cfg: LMConfig, *, chunk: int = 1024,
         nll = (logz - gold) * mk
         return (acc[0] + nll.sum(), acc[1] + mk.sum()), None
 
-    (tot, cnt), _ = jax.lax.scan(step, (jnp.zeros(()), jnp.zeros(())), (hs, ls, ms))
-    return tot / jnp.maximum(cnt, 1.0)
+    with jax.named_scope(SC.EMBED_HEAD):
+        hs = hidden.reshape(b, nc, chunk, d).swapaxes(0, 1)
+        ls = labels.reshape(b, nc, chunk).swapaxes(0, 1)
+        if mask is None:
+            ms = jnp.ones((nc, b, chunk), jnp.float32)
+        else:
+            ms = mask.reshape(b, nc, chunk).swapaxes(0, 1).astype(jnp.float32)
+        (tot, cnt), _ = jax.lax.scan(step, (jnp.zeros(()), jnp.zeros(())),
+                                     (hs, ls, ms))
+        return tot / jnp.maximum(cnt, 1.0)
 
 
 # ---------------------------------------------------------------------------

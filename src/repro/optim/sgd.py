@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 from repro.core import bdwp
 from repro.core import operand as O
+from repro.core import scopes as S
 from repro.core.sparsity import (SparsityConfig, _move_axis_last, nm_mask,
                                  nm_mask_pair, nm_mask_shared,
                                  nm_mask_transposable, nm_pack_from_mask,
@@ -232,7 +233,6 @@ def update(state, grads, opt_cfg: SGDConfig, sp_cfg: SparsityConfig,
     pack in one pass; the BP operand is derived jnp-side.  Bitwise
     identical to the jnp path.
     """
-    lr = lr_schedule(opt_cfg, state["step"])
     names = param_names or _names_of(state["master"])
     prev_masks = stored_decay_masks(prev_compute) if (
         pregen and prev_compute is not None) else {}
@@ -308,14 +308,17 @@ def update(state, grads, opt_cfg: SGDConfig, sp_cfg: SparsityConfig,
     flat_w, tdef = jax.tree_util.tree_flatten(state["master"])
     flat_g = jax.tree_util.tree_flatten(grads)[0]
     flat_v = jax.tree_util.tree_flatten(state["momentum"])[0]
-    outs = [upd(n, w, g, v) for n, w, g, v in zip(names, flat_w, flat_g, flat_v)]
+    with jax.named_scope(S.UPDATE):
+        lr = lr_schedule(opt_cfg, state["step"])  # jnp_upd, pallas_upd read it
+        outs = [upd(n, w, g, v)
+                for n, w, g, v in zip(names, flat_w, flat_g, flat_v)]
+        step = state["step"] + 1
     new_master = jax.tree_util.tree_unflatten(tdef, [o[0] for o in outs])
     new_mom = jax.tree_util.tree_unflatten(tdef, [o[1] for o in outs])
     # pre-generation: the compute operands written at WU time (Fig. 11c);
     # PregenOp "leaves" ride through unflatten as opaque pytree subtrees
     compute = jax.tree_util.tree_unflatten(tdef, [o[2] for o in outs])
-    new_state = {"master": new_master, "momentum": new_mom,
-                 "step": state["step"] + 1}
+    new_state = {"master": new_master, "momentum": new_mom, "step": step}
     return new_state, compute
 
 

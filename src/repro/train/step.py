@@ -337,6 +337,14 @@ def encdec_decode_step(params, cache, enc_out, token, pos, *, cfg, sp_cfg,
 # ---------------------------------------------------------------------------
 
 
+def _named(fn: partial) -> partial:
+    """``jax.jit`` names its program (``jit_<name>`` in a profiler trace)
+    after ``__name__``, which a ``functools.partial`` lacks
+    (``jit__unknown``): give it its function's."""
+    fn.__name__ = fn.func.__name__
+    return fn
+
+
 @dataclasses.dataclass
 class StepBundle:
     step_fn: callable            # jitted
@@ -392,12 +400,12 @@ def build_lm_train(cfg, mesh: Mesh, sp_cfg: SparsityConfig,
     batch_sh = jax.tree.map(lambda ps: NamedSharding(mesh, ps), in_pspecs,
                             is_leaf=lambda x: isinstance(x, P))
 
-    fn = partial(lm_train_step, cfg=cfg, sp_cfg=sp_cfg, opt_cfg=opt_cfg,
-                 mesh=mesh, names=names, compress=compress,
-                 grad_pspecs=p_pspecs, seq_parallel=seq_parallel,
-                 pregen=pregen, pregen_pack=pregen_pack,
-                 use_pallas=use_pallas, nm_backend=nm_backend,
-                 grad_sync=grad_sync)
+    fn = _named(partial(lm_train_step, cfg=cfg, sp_cfg=sp_cfg,
+                        opt_cfg=opt_cfg, mesh=mesh, names=names,
+                        compress=compress, grad_pspecs=p_pspecs,
+                        seq_parallel=seq_parallel, pregen=pregen,
+                        pregen_pack=pregen_pack, use_pallas=use_pallas,
+                        nm_backend=nm_backend, grad_sync=grad_sync))
     jitted = jax.jit(fn,
                      in_shardings=(state_sh, batch_sh),
                      out_shardings=(state_sh, None),
@@ -423,10 +431,10 @@ def build_encdec_train(cfg, mesh: Mesh, sp_cfg, opt_cfg,
                  "labels": P(dp, None)}
     batch_sh = jax.tree.map(lambda ps: NamedSharding(mesh, ps), in_pspecs,
                             is_leaf=lambda x: isinstance(x, P))
-    fn = partial(encdec_train_step, cfg=cfg, sp_cfg=sp_cfg, opt_cfg=opt_cfg,
-                 mesh=mesh, names=names, pregen=pregen,
-                 pregen_pack=pregen_pack, use_pallas=use_pallas,
-                 nm_backend=nm_backend)
+    fn = _named(partial(encdec_train_step, cfg=cfg, sp_cfg=sp_cfg,
+                        opt_cfg=opt_cfg, mesh=mesh, names=names,
+                        pregen=pregen, pregen_pack=pregen_pack,
+                        use_pallas=use_pallas, nm_backend=nm_backend))
     jitted = jax.jit(fn, in_shardings=(state_sh, batch_sh),
                      out_shardings=(state_sh, None),
                      donate_argnums=(0,) if donate else ())
@@ -532,12 +540,12 @@ def build_lm_serve(cfg, mesh: Mesh, sp_cfg: SparsityConfig, input_specs,
     in_sh = jax.tree.map(lambda ps: NamedSharding(mesh, ps), in_pspecs,
                          is_leaf=lambda x: isinstance(x, P))
     if prefill:
-        fn = partial(lm_prefill_step, cfg=cfg, sp_cfg=sp_cfg, mesh=mesh,
-                     long_context=long_context)
+        fn = _named(partial(lm_prefill_step, cfg=cfg, sp_cfg=sp_cfg,
+                            mesh=mesh, long_context=long_context))
         jitted = jax.jit(fn, in_shardings=(param_sh, in_sh))
     else:
-        fn = partial(lm_decode_step, cfg=cfg, sp_cfg=sp_cfg, mesh=mesh,
-                     long_context=long_context)
+        fn = _named(partial(lm_decode_step, cfg=cfg, sp_cfg=sp_cfg,
+                            mesh=mesh, long_context=long_context))
         jitted = jax.jit(
             fn,
             in_shardings=(param_sh, in_sh["cache"], in_sh["token"],
@@ -558,10 +566,12 @@ def build_encdec_serve(cfg, mesh: Mesh, sp_cfg, input_specs, *,
     in_sh = jax.tree.map(lambda ps: NamedSharding(mesh, ps), in_pspecs,
                          is_leaf=lambda x: isinstance(x, P))
     if prefill:
-        fn = partial(encdec_prefill_step, cfg=cfg, sp_cfg=sp_cfg, mesh=mesh)
+        fn = _named(partial(encdec_prefill_step, cfg=cfg, sp_cfg=sp_cfg,
+                            mesh=mesh))
         jitted = jax.jit(fn, in_shardings=(param_sh, in_sh))
     else:
-        fn = partial(encdec_decode_step, cfg=cfg, sp_cfg=sp_cfg, mesh=mesh)
+        fn = _named(partial(encdec_decode_step, cfg=cfg, sp_cfg=sp_cfg,
+                            mesh=mesh))
         jitted = jax.jit(
             fn,
             in_shardings=(param_sh, in_sh["cache"], in_sh["enc_out"],

@@ -6,6 +6,12 @@ The loop a launcher drives.  Composes:
   * CheckpointManager (async atomic saves every ``ckpt_every``),
   * StragglerMonitor + Heartbeat,
   * auto-resume (elastic: restores onto whatever mesh is current).
+
+Each step writes the profiler spans ``fit.data``, ``fit.dispatch``,
+``fit.sync`` (waiting for the loss) and ``fit.checkpoint``; without an
+active trace they cost a flag check.  There is no span around the whole
+step, so a device-idle gap falls inside the span of what the loop was
+doing then.
 """
 
 from __future__ import annotations
@@ -15,6 +21,7 @@ import time
 from typing import Callable, Iterator, Optional
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from repro.train.checkpoint import CheckpointManager
 from repro.train.fault import Heartbeat, StragglerMonitor
@@ -65,18 +72,27 @@ def fit(bundle, state, data_iter: Iterator, tcfg: TrainerConfig,
     history = []
     cur = int(state["step"])  # authoritative; advances with each update
     last_saved = None         # step of the most recent periodic save
-    for it_step, batch in data_iter:
+    batches = iter(data_iter)
+    while cur < tcfg.total_steps:
+        with TraceAnnotation("fit.data"):
+            # not next(): the profiler's Python tracer records that call
+            # as ``$builtins next``, which would outweigh this span where
+            # a trace reader names an idle gap by its longest host event
+            try:
+                it_step, batch = batches.__next__()
+            except StopIteration:
+                break
         if it_step < cur:  # stale iterator after a resume: fast-forward
             continue
-        if cur >= tcfg.total_steps:
-            break
         t0 = time.perf_counter()
-        state, metrics = bundle.step_fn(state, batch)
-        jax.block_until_ready(metrics["loss"])
-        dt = time.perf_counter() - t0
+        with TraceAnnotation("fit.dispatch"):
+            state, metrics = bundle.step_fn(state, batch)
+        with TraceAnnotation("fit.sync"):
+            jax.block_until_ready(metrics["loss"])
+            dt = time.perf_counter() - t0
+            loss = float(metrics["loss"])
         straggler = mon.record(cur, dt)
-        rec = {"step": cur, "loss": float(metrics["loss"]),
-               "sec": dt, "straggler": straggler}
+        rec = {"step": cur, "loss": loss, "sec": dt, "straggler": straggler}
         history.append(rec)
         if hb is not None:
             hb.beat(cur, loss=rec["loss"])
@@ -87,7 +103,8 @@ def fit(bundle, state, data_iter: Iterator, tcfg: TrainerConfig,
             log_fn(f"step {cur:5d} loss {rec['loss']:.4f} {dt*1e3:.1f}ms")
         cur += 1  # == int(state["step"]) without a device sync
         if ckpt is not None and cur % tcfg.ckpt_every == 0:
-            ckpt.save(cur, state)
+            with TraceAnnotation("fit.checkpoint"):
+                ckpt.save(cur, state)
             last_saved = cur
     if ckpt is not None:
         # final snapshot — but when the loop's last periodic save already

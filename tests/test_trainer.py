@@ -238,6 +238,32 @@ class TestHeartbeat:
 
 
 class TestTrainerLoop:
+    def test_fit_spans_once_per_step(self, tmp_path):
+        """Under a profiler trace ``fit`` writes one ``fit.data``,
+        ``fit.dispatch`` and ``fit.sync`` span a step."""
+        import glob
+        import types
+
+        from repro.train import trainer as TR
+
+        step = jax.jit(lambda s, b: ({"step": s["step"] + 1},
+                                     {"loss": b.sum()}))
+        bundle = types.SimpleNamespace(step_fn=step)
+        state = {"step": jnp.zeros((), jnp.int32)}
+        stream = ((t, jnp.full((4,), float(t))) for t in range(100))
+        step(state, jnp.zeros((4,)))   # compile outside the trace
+        with jax.profiler.trace(str(tmp_path)):
+            state, hist = TR.fit(bundle, state, stream,
+                                 TR.TrainerConfig(total_steps=5),
+                                 log_fn=lambda *_: None)
+        assert [h["step"] for h in hist] == list(range(5))
+        path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+        names = [e.name for p in jax.profiler.ProfileData.from_file(path).planes
+                 for line in p.lines for e in line.events]
+        for span in ("fit.data", "fit.dispatch", "fit.sync"):
+            assert names.count(span) == 5, span
+        assert names.count("fit.checkpoint") == 0
+
     def test_fit_runs_checkpoints_and_history(self, tmp_path):
         from repro.configs import get_arch
         from repro.core.sparsity import SparsityConfig
