@@ -1,12 +1,17 @@
 """Attention: GQA + RoPE + qk-norm + sliding-window + MLA, train & decode.
 
-Memory-sane by construction: training/prefill attention is chunked with
-an online-softmax accumulator (flash-attention recurrence in pure JAX),
-so lowering 32k-token prefill never materializes an S x S tensor.
-Sliding-window attention is *banded* — a scan over query chunks that
-dynamic-slices only the in-window KV span — so SWA costs O(S*W) FLOPs in
-the compiled HLO, not O(S^2) (this is what makes gemma3/hymba long_500k
-honest).
+Memory-sane by construction: no S x S tensor reaches HBM.  Training and
+prefill attention goes through ``dot_product_attention``, which picks
+from what it can observe: on TPU a plain causal self-attention runs the
+fused block-sparse Pallas splash kernel (blocks above the diagonal
+skipped, the backward recomputing scores from the logsumexp); every
+other call (CPU, MLA's unequal head dims, odd lengths, sequence
+parallelism) runs ``chunked_attention``, an online-softmax scan over KV
+chunks in pure JAX, as does encoder and cross attention (non-causal).  Sliding-window attention is *banded*
+— a scan over query chunks that dynamic-slices only the in-window KV
+span — so SWA costs O(S*W) FLOPs in the compiled HLO, not O(S^2) (this
+is what makes gemma3/hymba long_500k honest); it and decode stay pure
+JAX on every backend.
 
 All projections route through BDWP (core/bdwp) so N:M sparse training
 applies to attention weights exactly as the paper does for ViT.
@@ -14,14 +19,23 @@ applies to attention weights exactly as the paper does for ViT.
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import dataclasses
+import sys
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.splash_attention import (
+    splash_attention_kernel as splash,
+    splash_attention_mask as splash_mask,
+)
+from jax.sharding import PartitionSpec as P
 
 from repro.core.sparsity import SparsityConfig
 from repro.models import layers as L
+from repro.sharding import rules as R
 from repro.sharding.rules import BATCH, act
 
 NEG_INF = -1e30
@@ -154,6 +168,144 @@ def chunked_attention(q, k, v, *, causal: bool, q_offset, chunk_kv: int = 1024,
     out = acc / jnp.maximum(l[..., None], 1e-30)
     out = out.transpose(0, 3, 1, 2, 4).reshape(b, sq, h, dv)
     return out.astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Fused causal attention (Pallas splash kernel on TPU) and the dispatch
+# ---------------------------------------------------------------------------
+
+
+def _fused_block(s: int) -> Optional[int]:
+    """Block of the fused kernel along both sequence axes: the largest
+    of 512, 256, 128 that divides ``s`` (None: no such block)."""
+    return next((blk for blk in (512, 256, 128) if s % blk == 0), None)
+
+
+def _kernel_layout(b: int, h: int, hkv: int):
+    """How the fused kernel lies over the active activation mesh.
+
+    Returns ``(mesh, spec)``: the ``(B, H, S, D)`` PartitionSpec of a
+    ``shard_map`` with the batch over the dp axes and heads over
+    ``"model"``, or ``(None, None)`` on one device: an active mesh of
+    one, or no active mesh in a process that has one device.  Returns
+    None where the kernel cannot be laid out: sequence parallelism, no
+    active mesh among several devices (XLA cannot partition a Mosaic
+    kernel), a batch or head count the axes do not divide, or another
+    mesh axis of more than one device (a vmapped pod axis).
+    """
+    mesh, dp, sp = R.act_context()
+    if sp:
+        return None
+    if mesh is None:
+        return (None, None) if jax.device_count() == 1 else None
+    if mesh.size == 1:
+        return None, None
+    dp = tuple(a for a in (dp or ()) if a in mesh.axis_names)
+    n_dp = 1
+    for a in dp:
+        n_dp *= mesh.shape[a]
+    n_tp = mesh.shape.get("model", 1)
+    others = [a for a in mesh.axis_names
+              if a not in dp and a != "model" and mesh.shape[a] > 1]
+    if others or b % n_dp or h % n_tp or hkv % n_tp:
+        return None
+    return mesh, P(dp or None, "model" if n_tp > 1 else None, None, None)
+
+
+def fused_path_ok(q, k, v, *, causal: bool, q_offset, kv_len_mask) -> bool:
+    """Whether ``dot_product_attention`` runs the fused kernel.
+
+    All of: a TPU (the active mesh's, else the default backend); causal
+    with a static ``q_offset`` of 0 and no ``kv_len_mask``; as many
+    queries as keys, a multiple of 128; one head dim for q, k and v, a
+    multiple of 64 and at most 256; query heads a multiple of KV heads;
+    and a layout over the active mesh (``_kernel_layout``).
+    """
+    mesh = R.act_context()[0]
+    platform = (mesh.devices.flat[0].platform if mesh is not None
+                else jax.default_backend())
+    b, s, h, d = q.shape
+    return (platform == "tpu" and causal
+            and isinstance(q_offset, int) and q_offset == 0
+            and kv_len_mask is None
+            and k.shape[1] == s and _fused_block(s) is not None
+            and k.shape[-1] == v.shape[-1] == d and d % 64 == 0 and d <= 256
+            and h % k.shape[2] == 0
+            and _kernel_layout(b, h, k.shape[2]) is not None)
+
+
+def _splash_kernel(h: int, s: int, interpret: bool):
+    """The splash kernel for ``h`` query heads of ``s`` causal positions:
+    one block size (``_fused_block``) in every phase, and one backward
+    kernel for dq, dk and dv.  On TPU v5e at S 4096 that beat separate
+    dq and dkv kernels and blocks of 256 or 1024 (PERF.md §5)."""
+    blk = _fused_block(s)
+    sizes = splash.BlockSizes(
+        block_q=blk, block_kv=blk, block_kv_compute=blk,
+        block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
+        use_fused_bwd_kernel=True)
+    mask = splash_mask.MultiHeadMask([splash_mask.CausalMask((s, s))] * h)
+    return splash.make_splash_mha(mask, block_sizes=sizes, head_shards=1,
+                                  q_seq_shards=1, interpret=interpret)
+
+
+def fused_causal_attention(q, k, v, *, interpret: bool = False):
+    """Causal attention through the splash kernel: q (B, S, H, D),
+    k and v (B, S, Hkv, D), H a multiple of Hkv; ``interpret`` runs it
+    in Pallas interpret mode (tests on the CPU).
+
+    The kernel takes bf16 (the operand dtype) in, accumulates in fp32,
+    keeps fp32 softmax statistics and has no scale argument: the
+    ``D**-0.5`` scale goes onto q in fp32 before its one cast.
+    """
+    b, s, h, d = q.shape
+    qs = (q.astype(jnp.float32) * d ** -0.5).astype(k.dtype)
+    mesh, spec = _kernel_layout(b, h, k.shape[2])
+
+    def run(q_, k_, v_):  # (B, H, S, D), the local shard under a mesh
+        kernel = _splash_kernel(q_.shape[1], s, interpret)
+        return jax.vmap(kernel)(q_, k_, v_)
+
+    if mesh is not None:
+        run = jax.shard_map(run, mesh=mesh, in_specs=(spec,) * 3,
+                            out_specs=spec, check_vma=False)
+    out = run(*(x.swapaxes(1, 2) for x in (qs, k, v)))
+    return out.swapaxes(1, 2).astype(q.dtype)
+
+
+# Counters of the attention sites traced, by path; see ``count_paths``.
+_PATH_COUNTS: list = []
+
+
+@contextlib.contextmanager
+def count_paths(step: str):
+    """Count the ``dot_product_attention`` sites traced inside, by the
+    path each took, and report them on stderr when the trace is done.
+    A scanned layer stack is one site: its body is traced once."""
+    counts = collections.Counter()
+    _PATH_COUNTS.append(counts)
+    try:
+        yield counts
+    finally:
+        _PATH_COUNTS.remove(counts)
+    if counts:
+        print(f"{step}: attention sites traced: fused {counts['fused']}, "
+              f"chunked {counts['chunked']}", file=sys.stderr, flush=True)
+
+
+def dot_product_attention(q, k, v, *, causal: bool, q_offset=0,
+                          chunk_kv: int = 1024,
+                          kv_len_mask: Optional[int] = None):
+    """Training and prefill attention: the fused kernel where
+    ``fused_path_ok``, else ``chunked_attention``."""
+    fused = fused_path_ok(q, k, v, causal=causal, q_offset=q_offset,
+                          kv_len_mask=kv_len_mask)
+    for counts in _PATH_COUNTS:
+        counts["fused" if fused else "chunked"] += 1
+    if fused:
+        return fused_causal_attention(q, k, v)
+    return chunked_attention(q, k, v, causal=causal, q_offset=q_offset,
+                             chunk_kv=chunk_kv, kv_len_mask=kv_len_mask)
 
 
 def banded_attention(q, k, v, *, window: int, chunk_q: int = 1024):
@@ -310,8 +462,8 @@ def attn_apply(p, x, cfg: AttnConfig, sp_cfg: SparsityConfig, *,
         if window is not None:
             out = banded_attention(q, k, v, window=window, chunk_q=cfg.chunk_q)
         else:
-            out = chunked_attention(q, k, v, causal=True, q_offset=0,
-                                    chunk_kv=cfg.chunk_kv)
+            out = dot_product_attention(q, k, v, causal=True,
+                                        chunk_kv=cfg.chunk_kv)
         new_cache = None
         if cache is not None:  # prefill: fill the cache
             k_cache = jax.lax.dynamic_update_slice_in_dim(
@@ -385,9 +537,8 @@ def _mla_apply(p, x, cfg: AttnConfig, sp_cfg, *, positions, cache, decode,
         q = jnp.concatenate([q_nope, q_pe], axis=-1)
         k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe[..., None, :],
                                                       (*k_pe.shape[:-1], h, dr))], axis=-1)
-        out5 = chunked_attention(q, k, val, causal=True, q_offset=0,
-                                 chunk_kv=cfg.chunk_kv)
-        ctx = out5
+        ctx = dot_product_attention(q, k, val, causal=True,
+                                    chunk_kv=cfg.chunk_kv)
         new_cache = None
         if cache is not None:
             ckv_c = jax.lax.dynamic_update_slice_in_dim(cache["ckv"], ckv.astype(cache["ckv"].dtype), 0, axis=1)

@@ -501,6 +501,11 @@ def activation_sharding(mesh: Optional[Mesh], dp, sp: bool = False):
         _ACT_CTX.update(old)
 
 
+def act_context():
+    """The active context: (mesh, dp axes, sequence parallel)."""
+    return _ACT_CTX["mesh"], _ACT_CTX["dp"], _ACT_CTX["sp"]
+
+
 def act(x, *axes):
     """Constrain an activation under the ambient context.
 

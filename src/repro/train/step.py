@@ -23,6 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import operand as O
 from repro.core.sparsity import SparsityConfig
+from repro.models import attention as A
 from repro.models import encdec as E
 from repro.models import transformer_lm as T
 from repro.optim import compress as C
@@ -121,7 +122,7 @@ def lm_train_step(state, batch, *, cfg, sp_cfg, opt_cfg, mesh, names,
     compress_on = compress and "pod" in mesh.axis_names
     dp = ("data",) if compress_on else R.batch_axes(mesh)
     with R.activation_sharding(mesh, dp, sp=seq_parallel), \
-            O.backend_scope(nm_backend):
+            O.backend_scope(nm_backend), A.count_paths("lm_train_step"):
         if pregen:
             # FF/BP load the operands written at the previous WU — no
             # per-step master cast, no in-model mask derivation; packed
@@ -270,7 +271,8 @@ def lm_prefill_step(params, batch, *, cfg, sp_cfg, mesh=None,
     b, s = batch["tokens"].shape
     prefix = batch.get("prefix_embeds")
     s_tot = s + (prefix.shape[1] if prefix is not None else 0)
-    with R.activation_sharding(mesh, _serve_dp(mesh, long_context)):
+    with R.activation_sharding(mesh, _serve_dp(mesh, long_context)), \
+            A.count_paths("lm_prefill_step"):
         cache = T.init_lm_cache(cfg, b, s_tot)
         hidden, cache, _ = T.forward(params, batch["tokens"], cfg, sp_cfg,
                                      prefix_embeds=prefix, cache=cache)
