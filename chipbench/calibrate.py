@@ -7,10 +7,10 @@ Not run by the benchmark's own runs.
 
 For each seed the program's first steps are compared with the reference
 as a benchmark run compares them (the lower readings).  For each control
-seed the reference computed in float8 (e4m3, per-tensor scale) takes the
-program's place (the control), and so does the reference on half of each
-batch (a planted fault).  One JSON line per reading on stdout and in
-``<--out>/calib_<workload>.jsonl``.
+seed the reference computed one precision below the configuration's (the
+family's control) takes the program's place, and so does the reference
+on half of each batch (a planted fault).  One JSON line per reading on
+stdout and in ``<--out>/calib_<workload>.jsonl``.
 """
 
 from __future__ import annotations
@@ -39,10 +39,8 @@ def emit(out: Path, rec: dict) -> None:
         f.write(line + "\n")
 
 
-def train(conf, mix, seeds, control, out, devices) -> None:
-    from chipbench import reference as RF
-    from chipbench import train_cell as TC
-
+def readings(conf, mix, seeds, control, out, devices) -> None:
+    TC = spec.runner(mix["kind"])
     b = TC.build(conf, mix, log, devices)
     for seed in seeds:
         st = TC.first_steps(b, seed, log)
@@ -55,7 +53,7 @@ def train(conf, mix, seeds, control, out, devices) -> None:
         emit(out, {"seed": seed, "what": "program", "ref_s": time.perf_counter() - t0,
                    **got})
         if seed in control:
-            for what, kw in (("control_fp8", {"q": RF.fp8}),
+            for what, kw in (("control", {"control": True}),
                              ("fault_half_batch", {"drop_half": True})):
                 other = TC.reference_readings(conf, mix, seed, **kw)
                 emit(out, {"seed": seed, "what": what, **TC.compare(other, ref)})
@@ -87,7 +85,7 @@ def main(argv=None) -> int:
         return 3
     out = Path(args.out) / f"calib_{args.workload}.jsonl"
     out.parent.mkdir(parents=True, exist_ok=True)
-    train(conf, mix, seeds, control, out, devices[:cell["chips"]])
+    readings(conf, mix, seeds, control, out, devices[:cell["chips"]])
     return 0
 
 

@@ -1,18 +1,17 @@
-"""Plain float32 reference of the benchmark's decoder LMs.
+"""Plain float32 pieces that a family's reference is built from.
 
 Written from the model's equations, with no import from the program:
-its own initialisation from the seed (the same key schedule and draws as
-the program's, so both start from the same weights without one handing
-the other anything), RMSNorm, RoPE, causal GQA attention, SwiGLU MLP or
-top-k MoE with per-group capacity, and the BDWP N:M training semantics:
+RMSNorm, RoPE, causal GQA attention, top-k routing with per-group
+capacity, the head and its loss, and the BDWP N:M training semantics:
 
     FF : y  = x @ (mask_ff(W) * W)      N of every M along the input axis
     BP : dx = g @ (mask_bp(W) * W)^T    N of every M along the output axis
     WU : dW = x^T @ g                   dense
 
 followed by momentum SGD with weight decay and SR-STE's decay of the
-pruned weights.  Masks keep the N largest |w| of each group of M, ties
-to the earlier entry.
+pruned weights (``train_readings``, over the ``Net`` a family gives).
+Masks keep the N largest |w| of each group of M, ties to the earlier
+entry.
 
 Every matmul goes through ``mm(a, b, q)``: ``q = exact`` is the
 reference, run under ``jax.default_matmul_precision("highest")``;
@@ -27,15 +26,13 @@ so the harness can compare the two leaf by leaf.
 from __future__ import annotations
 
 from functools import lru_cache, partial
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 AUX_COEF = 0.01          # weight of the MoE load-balance loss
 NEG = -1e30
-PRUNED = ("attn/q_proj/w", "attn/k_proj/w", "attn/v_proj/w", "attn/o_proj/w",
-          "moe/w_gate", "moe/w_up", "moe/w_down",
-          "ffn/w_gate/w", "ffn/w_up/w", "ffn/w_down/w")
 
 
 # ---------------------------------------------------------------------------
@@ -63,61 +60,6 @@ fp8.defvjp(lambda x: (fp8(x), None), lambda _, g: (g,))
 
 def mm(a, b, q):
     return jnp.matmul(q(a), q(b), preferred_element_type=jnp.float32)
-
-
-# ---------------------------------------------------------------------------
-# weights
-# ---------------------------------------------------------------------------
-
-
-def init(key, c) -> dict:
-    """Flat {program tree path: float32 array}, drawn from ``key``."""
-    d = c.d_model
-    k_embed, k_blocks, _, k_out = jax.random.split(key, 4)
-    nrm = jax.random.normal
-    p = {"embed/embed_table":
-         nrm(k_embed, (c.padded_vocab, d), jnp.float32) * d ** -0.5}
-    blocks = jax.vmap(partial(_block_init, c=c))(
-        jax.random.split(k_blocks, c.n_layers))
-    p.update({"blocks/" + k: v for k, v in blocks.items()})
-    p["final_norm/norm_scale"] = jnp.ones((d,), jnp.float32)
-    if not c.tie_embed:
-        p["lm_head/w"] = nrm(k_out, (d, c.padded_vocab), jnp.float32) * d ** -0.5
-    return p
-
-
-def _block_init(key, c) -> dict:
-    d, h, kv, hd = c.d_model, c.n_heads, c.n_kv, c.head_dim
-    nrm = jax.random.normal
-    ks = jax.random.split(key, 6)
-    a = jax.random.split(ks[0], 8)
-    p = {"ln1/norm_scale": jnp.ones((d,), jnp.float32),
-         "ln2/norm_scale": jnp.ones((d,), jnp.float32),
-         "attn/q_proj/w": nrm(a[0], (d, h * hd), jnp.float32) * d ** -0.5,
-         "attn/k_proj/w": nrm(a[1], (d, kv * hd), jnp.float32) * d ** -0.5,
-         "attn/v_proj/w": nrm(a[2], (d, kv * hd), jnp.float32) * d ** -0.5,
-         "attn/o_proj/w": nrm(a[3], (h * hd, d), jnp.float32) * (h * hd) ** -0.5}
-    if c.qk_norm:
-        p["attn/q_norm/norm_scale"] = jnp.ones((hd,), jnp.float32)
-        p["attn/k_norm/norm_scale"] = jnp.ones((hd,), jnp.float32)
-    if c.moe:
-        e, f = c.n_experts, c.d_expert
-        m = jax.random.split(ks[2], 8)
-        p["moe/router/w"] = nrm(m[0], (d, e), jnp.float32) * d ** -0.5
-        p["moe/w_gate"] = nrm(m[1], (e, d, f), jnp.float32) * d ** -0.5
-        p["moe/w_up"] = nrm(m[2], (e, d, f), jnp.float32) * d ** -0.5
-        p["moe/w_down"] = nrm(m[3], (e, f, d), jnp.float32) * f ** -0.5
-    else:
-        f = c.d_ff
-        g, u, dn = jax.random.split(ks[3], 3)
-        p["ffn/w_gate/w"] = nrm(g, (d, f), jnp.float32) * d ** -0.5
-        p["ffn/w_up/w"] = nrm(u, (d, f), jnp.float32) * d ** -0.5
-        p["ffn/w_down/w"] = nrm(dn, (f, d), jnp.float32) * f ** -0.5
-    return p
-
-
-def is_pruned(name: str) -> bool:
-    return any(name.endswith(s) for s in PRUNED)
 
 
 def nm_keep(w, n: int, m: int, axis: int):
@@ -230,84 +172,6 @@ def routing_group(c, tokens: int) -> int:
     return g
 
 
-def moe(p, x, c, lin, q, subject, group):
-    """Top-k routed SwiGLU experts; returns (y, load-balance loss).
-    ``group``: tokens per routing group, counted along the rows of ``x``
-    flattened (training) or within each row (a prefilled prompt)."""
-    r, s, d = x.shape
-    e, k = c.n_experts, c.top_k
-    logits = mm(x, p["moe/router/w"], q)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top, idx = jax.lax.top_k(probs, k)
-    top = top / jnp.maximum(top.sum(-1, keepdims=True), 1e-9)
-    cap = min(int(max(k, round(group * c.capacity_factor * k / e))), group)
-    if s % group:   # groups run across rows: route the rows as one
-        keep = capacity_keep(idx.reshape(1, r * s, k), subject.reshape(1, -1),
-                             e, group, cap).reshape(r, s, k)
-    else:
-        keep = capacity_keep(idx, subject, e, group, cap)
-    gates = (jax.nn.one_hot(idx, e) * (top * keep)[..., None]).sum(2)  # (r,s,e)
-
-    def expert(acc, xs):
-        wg, wu, wd, ge = xs
-        hdn = jax.nn.silu(lin(x, wg)) * lin(x, wu)
-        return acc + lin(hdn, wd) * ge[..., None], None
-
-    expert = jax.checkpoint(expert)
-    y, _ = jax.lax.scan(expert, jnp.zeros_like(x),
-                        (p["moe/w_gate"], p["moe/w_up"], p["moe/w_down"],
-                         jnp.moveaxis(gates, -1, 0)))
-    counts = jax.lax.stop_gradient(
-        (jax.nn.one_hot(idx, e) * keep[..., None]).sum((0, 1, 2)))
-    aux = e * jnp.sum(probs.mean((0, 1)) * counts / jnp.maximum(counts.sum(), 1.0))
-    return y, aux
-
-
-def block(p, x, positions, subject, group, c, lin, q):
-    h = rmsnorm(x, p["ln1/norm_scale"], c.rms_eps)
-    b, s, _ = x.shape
-    qh = lin(h, p["attn/q_proj/w"]).reshape(b, s, c.n_heads, c.head_dim)
-    kh = lin(h, p["attn/k_proj/w"]).reshape(b, s, c.n_kv, c.head_dim)
-    vh = lin(h, p["attn/v_proj/w"]).reshape(b, s, c.n_kv, c.head_dim)
-    if c.qk_norm:
-        qh = rmsnorm(qh, p["attn/q_norm/norm_scale"], c.rms_eps)
-        kh = rmsnorm(kh, p["attn/k_norm/norm_scale"], c.rms_eps)
-    qh = rope(qh, positions, c.rope_theta)
-    kh = rope(kh, positions, c.rope_theta)
-    x = x + lin(attention(qh, kh, vh, q), p["attn/o_proj/w"])
-    h = rmsnorm(x, p["ln2/norm_scale"], c.rms_eps)
-    if c.moe:
-        y, aux = moe(p, h, c, lin, q, subject, group)
-    else:
-        y = lin(jax.nn.silu(lin(h, p["ffn/w_gate/w"])) * lin(h, p["ffn/w_up/w"]),
-                p["ffn/w_down/w"])
-        aux = jnp.zeros((), jnp.float32)
-    return x + y, aux
-
-
-def hidden(p, tokens, c, lin, q, subject=None, group=None):
-    """Final-norm hidden states (B, S, d) and the summed MoE loss.
-    Without ``subject``/``group`` every token is routed under capacity,
-    all rows together (a training batch)."""
-    b, s = tokens.shape
-    x = p["embed/embed_table"][tokens].astype(jnp.float32)
-    positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-    if subject is None:
-        subject = jnp.ones((b, s), bool)
-    if group is None:
-        group = routing_group(c, b * s)
-    layers = {k[len("blocks/"):]: v for k, v in p.items()
-              if k.startswith("blocks/")}
-
-    @jax.checkpoint
-    def body(x, lp):
-        lp = {k: v.astype(jnp.float32) for k, v in lp.items()}
-        return block(lp, x, positions, subject, group, c, lin, q)
-
-    x, aux = jax.lax.scan(body, x, layers)
-    return rmsnorm(x, p["final_norm/norm_scale"], c.rms_eps), aux.sum()
-
-
 def logits(p, h, c, q):
     table = p["embed/embed_table"].T if c.tie_embed else p["lm_head/w"]
     out = mm(h, table, q)
@@ -336,14 +200,25 @@ def lm_loss(p, h, labels, c, q, chunk: int = 1024):
 # ---------------------------------------------------------------------------
 
 
-def train_step_fn(c, n: int, m: int, lr: float, momentum: float,
+class Net(NamedTuple):
+    """What a family gives the training steps: ``init(key, c)`` -> {leaf:
+    float32 array} drawn from ``key``; ``hidden(p, tokens, c, lin, q)`` ->
+    (final-norm hidden states, summed MoE loss); ``is_pruned(leaf)``:
+    whether SR-STE decays the leaf's pruned weights."""
+
+    init: Callable
+    hidden: Callable
+    is_pruned: Callable
+
+
+def train_step_fn(net: Net, c, n: int, m: int, lr: float, momentum: float,
                   weight_decay: float, lam: float, q=exact):
     """jit-able (params, momentum, tokens, labels) -> (params, momentum,
     loss, per-leaf gradient norms)."""
     lin = make_linear(n, m, q)
 
     def total(p, tokens, labels):
-        h, aux = hidden(p, tokens, c, lin, q)
+        h, aux = net.hidden(p, tokens, c, lin, q)
         ce = lm_loss(p, h, labels, c, q)
         return ce + AUX_COEF * aux, ce
 
@@ -353,7 +228,7 @@ def train_step_fn(c, n: int, m: int, lr: float, momentum: float,
         new_p, new_v = {}, {}
         for k, w in p.items():
             gk = g[k] + weight_decay * w
-            if is_pruned(k):
+            if net.is_pruned(k):
                 ff = nm_keep(w, n, m, w.ndim - 2)
                 gk = gk + lam * jnp.where(ff, 0.0, w)
             new_v[k] = momentum * v[k] + gk
@@ -364,35 +239,36 @@ def train_step_fn(c, n: int, m: int, lr: float, momentum: float,
 
 
 @lru_cache(maxsize=None)
-def _jit_init(c):
+def _jit_init(init, c):
     return jax.jit(partial(init, c=c))
 
 
 @lru_cache(maxsize=None)
-def _jit_train_step(c, n, m, lr, momentum, weight_decay, lam, q):
-    return jax.jit(train_step_fn(c, n, m, lr, momentum, weight_decay, lam, q),
+def _jit_train_step(net, c, n, m, lr, momentum, weight_decay, lam, q):
+    return jax.jit(train_step_fn(net, c, n, m, lr, momentum, weight_decay, lam, q),
                    donate_argnums=(0, 1))
 
 
 @lru_cache(maxsize=None)
-def _jit_change(c):
-    make = _jit_init(c)
+def _jit_change(init, c):
+    make = _jit_init(init, c)
     return jax.jit(lambda p, k: {n_: jnp.linalg.norm((p[n_] - w).ravel())
                                  for n_, w in make(k).items()})
 
 
-def train_readings(c, key, batches, *, n: int, m: int, lr: float,
+def train_readings(net: Net, c, key, batches, *, n: int, m: int, lr: float,
                    momentum: float, weight_decay: float, lam: float, q=exact,
                    drop_half: bool = False):
-    """Follow the first ``len(batches)`` steps from the seed's weights.
-    Returns {"loss": [...], "grad": {leaf: |g0|}, "change": {leaf:
-    |w_T - w_0|}} as host numbers.  ``drop_half`` leaves out the second
-    half of every batch (a planted fault, read against the whole)."""
+    """Follow the first ``len(batches)`` steps of ``net`` at sizes ``c``
+    from the seed's weights.  Returns {"loss": [...], "grad": {leaf: |g0|},
+    "change": {leaf: |w_T - w_0|}} as host numbers.  ``drop_half`` leaves
+    out the second half of every batch (a planted fault, read against the
+    whole)."""
     with jax.default_matmul_precision("highest"):
-        make = _jit_init(c)
+        make = _jit_init(net.init, c)
         p = make(key)
         v = jax.tree.map(jnp.zeros_like, p)
-        step = _jit_train_step(c, n, m, lr, momentum, weight_decay, lam, q)
+        step = _jit_train_step(net, c, n, m, lr, momentum, weight_decay, lam, q)
         losses, grad = [], None
         for tokens, labels in batches:
             if drop_half:   # half of the rows, or of the one row's tokens
@@ -404,5 +280,5 @@ def train_readings(c, key, batches, *, n: int, m: int, lr: float,
             if grad is None:
                 grad = {k: float(x) for k, x in gnorm.items()}
         del v
-        change = {k: float(x) for k, x in _jit_change(c)(p, key).items()}
+        change = {k: float(x) for k, x in _jit_change(net.init, c)(p, key).items()}
     return {"loss": losses, "grad": grad, "change": change}

@@ -4,7 +4,7 @@ host's lag in seeing each step end, from a traced window.
 The program names its layers with ``jax.named_scope`` (names from
 ``repro.core.scopes``), and XLA keeps each instruction's scope path as
 ``metadata={op_name=...}`` in the compiled text.  After the window,
-``layer_ms`` builds the cell's step again (``train_cell.build``: the
+``layer_ms`` builds the cell's step again (the runner's ``build``: the
 same jit and shardings, so the same instruction names), maps each instruction of its text to a layer (``layer_table``),
 and applies that to the self times of the ops that ran inside the
 window's runs of the step program (``lm_train_step``).  Each layer's
@@ -26,11 +26,13 @@ which instructions missing from the table hold over 1% of op time.
 
 from __future__ import annotations
 
+import ast
 import bisect
 import re
 import statistics
 import time
 from collections import defaultdict
+from pathlib import Path
 
 from chipbench import trace as TRC
 
@@ -38,10 +40,27 @@ STEP = "lm_train_step"
 SYNC = "fit.sync"
 MISSING_LIMIT = 0.01
 
+
+def declared(directory: Path) -> tuple:
+    """The scope names that the readers in ``directory`` declare, each in
+    a top-level ``SCOPES = (...)``, read without running the readers."""
+    out = []
+    for path in sorted(directory.glob("*.py")):
+        for node in ast.parse(path.read_text()).body:
+            if (isinstance(node, ast.Assign) and len(node.targets) == 1
+                    and getattr(node.targets[0], "id", None) == "SCOPES"):
+                out += [s for s in ast.literal_eval(node.value) if s not in out]
+    return tuple(out)
+
+
 # the scope names of repro.core.scopes, held here so that the yardstick
-# does not move with the program (tests/test_scopes.py holds them equal)
-LAYERS = ("ff", "bp", "wu", "attention", "moe_dispatch", "blocks",
-          "embed_head", "update")
+# does not move with the program: those of every train step, then any
+# that a reader of metrics/ declares (tests/test_scopes.py holds them
+# equal to the program's)
+BASE = ("ff", "bp", "wu", "attention", "moe_dispatch", "blocks",
+        "embed_head", "update")
+LAYERS = BASE + tuple(s for s in declared(Path(__file__).parent / "metrics")
+                      if s not in BASE)
 UNSCOPED = "unscoped"
 
 _WORD = re.compile(r"\w+")
@@ -201,15 +220,15 @@ def _step_text(ctx, n_devices: int):
     afresh."""
     import jax
 
-    from chipbench import train_cell
+    from chipbench import spec
 
     flag = "jax_compilation_cache_include_metadata_in_key"
     was = getattr(jax.config, flag)
     jax.config.update(flag, True)
     t0 = time.perf_counter()
     try:
-        b = train_cell.build(ctx["conf"], ctx["mix"], ctx["log"],
-                             jax.devices()[:n_devices])
+        b = spec.runner(ctx["mix"]["kind"]).build(
+            ctx["conf"], ctx["mix"], ctx["log"], jax.devices()[:n_devices])
     finally:
         jax.config.update(flag, was)
     ctx["log"](f"layer time: step built again in "

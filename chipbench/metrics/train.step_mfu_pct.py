@@ -1,8 +1,8 @@
 """Share of the chip's bf16 peak that the training steps reach while
-their programs run: the BDWP operation count per token (``flops.py``)
-times the tokens trained in the window, over the device time of the
-programs that ran in the traced window (the window runs only the train
-step) times the peak.  Time between programs is the train loop's, read
+their programs run: the BDWP operation count per token (the family's
+``train_flops_per_token``) times the tokens trained in the window, over
+the device time of the programs that ran in the traced window (the
+window runs only the train step) times the peak.  Time between programs is the train loop's, read
 by ``train.device_idle_pct``."""
 
 from chipbench import trace as TRC

@@ -6,12 +6,14 @@
 The cell (``BENCHMARK.json`` ``workloads``) names a configuration
 (``chipbench/configs/<config>.json``) and a traffic mix
 (``chipbench/traffic/<traffic>.json``); the mix's ``kind`` picks the
-module that runs it (``train_cell``).  Set-up builds the program's
-objects from the seed on the cell's chips, warms every shape the window
-uses and drives the first steps that the reference will follow; it ends
-by collecting and freezing the set-up's garbage, so that no collection
-of it falls in the window.  The window then runs for ``--seconds``; after it the
-reference decides ``correct``.
+module that runs it (``chipbench/<kind>_cell.py``), and the
+configuration's ``family`` the module that maps it to the program and
+holds its reference (``chipbench/families/<family>.py``).  Set-up builds
+the program's objects from the seed on the cell's chips, warms every
+shape the window uses and drives the first steps that the reference
+will follow; it ends by collecting and freezing the set-up's garbage, so
+that no collection of it falls in the window.  The window then runs for
+``--seconds``; after it the reference decides ``correct``.
 
 With ``--trace 0`` the result reports the cell's end-to-end metrics,
 with ``--trace 1`` its per-layer metrics, read by
@@ -139,11 +141,10 @@ def per_layer(bench: dict, workload: str, ctx: dict) -> dict:
 
 def run(args, bench: dict, cell: dict, conf: dict, mix: dict, devices) -> dict:
     """Drive one run; returns the result object (without printing it)."""
-    from chipbench import train_cell
     from repro.launch.compile_cache import setup_compile_cache
 
     setup_compile_cache()
-    runner = {"train": train_cell}[mix["kind"]]
+    runner = spec.runner(mix["kind"])
     limits = spec.limits(cell["name"])
     counter = CompileCounter()
     gcw = GCWatch()
@@ -183,7 +184,7 @@ def run(args, bench: dict, cell: dict, conf: dict, mix: dict, devices) -> dict:
                     f"longest gap between runs {max(gaps, default=0.0):.4f} s")
             ctx = {"trace": tr, "window": win, "window_s": win[1] - win[0],
                    "busy_s": TRC.busy_s(tr, win), "res": res, "mix": mix,
-                   "conf": conf, "model": spec.model(conf), "peak_bytes": peak,
+                   "conf": conf, "peak_bytes": peak,
                    "peaks": spec.peaks(devices[0].device_kind), "log": log}
             traced = {
                 "metrics": per_layer(bench, cell["name"], ctx),

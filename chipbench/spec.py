@@ -1,13 +1,15 @@
 """What a cell is, found by name: BENCHMARK.json, configuration files,
-traffic files, per-layer metric readers and the table of peaks.
+traffic files, the module that runs a mix's kind, a configuration's
+model family, per-layer metric readers and the table of peaks.
 
-Nothing here imports JAX or the program, so the tests and the harness's
-argument handling load it without touching a device.
+Nothing here imports JAX or the program (only the modules ``runner``
+and ``family`` look up may), so the tests and the harness's argument
+handling load it without touching a device.
 """
 
 from __future__ import annotations
 
-import dataclasses
+import importlib
 import importlib.util
 import json
 from pathlib import Path
@@ -56,6 +58,28 @@ def peaks(device_kind: str, root: Path = ROOT) -> dict:
     return table[device_kind]
 
 
+def by_name(*parts: str, what: str):
+    """The module ``chipbench/<parts>.py``; where that file is missing, an
+    error that names it."""
+    path = ("chipbench", *parts)
+    if not (all(p.isidentifier() for p in parts)
+            and importlib.util.find_spec(".".join(path))):
+        raise ModuleNotFoundError(f"no {what}: looked for {'/'.join(path)}.py")
+    return importlib.import_module(".".join(path))
+
+
+def runner(kind: str):
+    """The module that runs a traffic mix of this ``kind``:
+    ``<kind>_cell.py``, with ``setup``, ``window``, ``check``, ``build``."""
+    return by_name(f"{kind}_cell", what=f"runner for the kind {kind!r}")
+
+
+def family(conf: dict):
+    """The model family a configuration file names: ``families/<family>.py``."""
+    return by_name("families", conf["family"],
+                   what=f"model family {conf['family']!r}")
+
+
 def metric_reader(name: str, root: Path = ROOT):
     """The ``read(ctx)`` function of ``metrics/<name>.py``."""
     path = root / "metrics" / f"{name}.py"
@@ -72,55 +96,3 @@ def cell_metrics(bench: dict, workload: str, trace: bool) -> list:
     group = bench["per_layer"] if trace else bench["end_to_end"]
     return [m for m in group
             if "workloads" not in m or workload in m["workloads"]]
-
-
-@dataclasses.dataclass(frozen=True)
-class Model:
-    """The sizes of a configuration file, under the benchmark's own names.
-    The reference and the operation counts read only this."""
-
-    d_model: int
-    n_layers: int
-    n_heads: int
-    n_kv: int
-    head_dim: int
-    vocab: int
-    d_ff: int = 0
-    n_experts: int = 0
-    top_k: int = 0
-    d_expert: int = 0
-    tie_embed: bool = True
-    qk_norm: bool = False
-    rope_theta: float = 1e4
-    rms_eps: float = 1e-6
-    pad_vocab_to: int = 256
-    capacity_factor: float = 1.25
-    group_size: int = 512
-
-    @property
-    def padded_vocab(self) -> int:
-        return -(-self.vocab // self.pad_vocab_to) * self.pad_vocab_to
-
-    @property
-    def moe(self) -> bool:
-        return self.n_experts > 0
-
-
-def model(cfg: dict) -> Model:
-    """Configuration file (Hugging Face key names) -> Model."""
-    heads = cfg["num_attention_heads"]
-    experts = cfg.get("num_local_experts", 0)
-    return Model(
-        d_model=cfg["hidden_size"], n_layers=cfg["num_hidden_layers"],
-        n_heads=heads, n_kv=cfg["num_key_value_heads"],
-        head_dim=cfg.get("head_dim", cfg["hidden_size"] // heads),
-        vocab=cfg["vocab_size"],
-        d_ff=0 if experts else cfg["intermediate_size"],
-        n_experts=experts, top_k=cfg.get("num_experts_per_tok", 0),
-        d_expert=cfg["intermediate_size"] if experts else 0,
-        tie_embed=cfg["tie_word_embeddings"],
-        qk_norm=cfg.get("qk_norm", False), rope_theta=cfg["rope_theta"],
-        rms_eps=cfg["rms_norm_eps"],
-        pad_vocab_to=cfg["program"]["pad_vocab_to"],
-        capacity_factor=cfg["program"].get("moe_capacity_factor", 1.25),
-        group_size=cfg["program"].get("moe_group_size", 512))

@@ -2,7 +2,8 @@
 own smoke presets, under the same keys as the cell files."""
 
 GRANITE = {
-    "name": "granite-smoke", "arch": "granite-moe-1b-a400m", "preset": "smoke",
+    "name": "granite-smoke", "family": "decoder_lm",
+    "arch": "granite-moe-1b-a400m", "preset": "smoke",
     "hidden_size": 64, "intermediate_size": 32, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 16, "num_hidden_layers": 2,
     "num_local_experts": 8, "num_experts_per_tok": 2, "vocab_size": 512,
@@ -12,7 +13,8 @@ GRANITE = {
 }
 
 QWEN = {
-    "name": "qwen-smoke", "arch": "qwen3-8b", "preset": "smoke",
+    "name": "qwen-smoke", "family": "decoder_lm",
+    "arch": "qwen3-8b", "preset": "smoke",
     "hidden_size": 64, "intermediate_size": 128, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 16, "num_hidden_layers": 2,
     "vocab_size": 512, "rope_theta": 1000000.0, "rms_norm_eps": 1e-06,

@@ -141,7 +141,7 @@ assert scopes(step("ff").as_text()) == {"ff"}
 assert scopes(step("bp").as_text()) == {"ff"}    # the cache's own key
 train_cell.build = lambda conf, mix, log, devices: {
     "bundle": types.SimpleNamespace(step_fn=step("bp"))}
-text = LT._step_text({"conf": None, "mix": None, "log": print}, 1)
+text = LT._step_text({"conf": None, "mix": {"kind": "train"}, "log": print}, 1)
 assert scopes(text) == {"bp"}, scopes(text)
 '''
 
@@ -160,3 +160,44 @@ def test_the_rebuild_reads_its_own_scope_names(tmp_path):
                PYTHONPATH=os.pathsep.join([str(repo / "src"), str(repo)]))
     subprocess.run([sys.executable, "-c", REBUILD, str(tmp_path)], env=env,
                    cwd=repo, check=True, timeout=300)
+
+
+DECLARED = r'''
+import sys
+from chipbench import layer_time as LT
+
+assert LT.LAYERS == LT.BASE + ("mla_latent",), LT.LAYERS
+assert LT.layer_of("jit(f)/transpose(jvp(blocks))/mla_latent/dot_general") == "mla_latent"
+table = LT.layer_table(sys.argv[1])
+assert table["d"] == ("mla_latent", False), table
+'''
+
+TABLE_HLO = """HloModule jit_t
+
+ENTRY %main (x: f32[4]) -> f32[4] {
+  %x = f32[4]{0} parameter(0)
+  ROOT %d = f32[4]{0} add(f32[4]{0} %x, f32[4]{0} %x), metadata={op_name="jit(t)/blocks/mla_latent/add"}
+}
+"""
+
+
+def test_a_scope_declared_by_a_reader_file_is_a_layer(tmp_path):
+    """A reader added as a new file that declares a scope of its own makes
+    that scope a layer of the table, with no edit to ``layer_time.py``."""
+    import os
+    import shutil
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    repo = Path(LT.__file__).resolve().parents[1]
+    shutil.copytree(repo / "chipbench", tmp_path / "chipbench",
+                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    (tmp_path / "chipbench" / "metrics" / "train.mla_latent_ms.py").write_text(
+        'from chipbench import layer_time as LT\n\nSCOPES = ("mla_latent",)\n\n\n'
+        'def read(ctx):\n    return LT.read_layer(ctx, "mla_latent")\n')
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join([str(tmp_path), str(repo / "src")]))
+    subprocess.run([sys.executable, "-c", DECLARED, TABLE_HLO], env=env,
+                   cwd=tmp_path, check=True, timeout=300)
+    assert "mla_latent" not in LT.LAYERS

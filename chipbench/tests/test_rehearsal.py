@@ -3,9 +3,14 @@ harness's own ``run``: the chip is not looked for (the test hands ``run``
 the CPU device), and the limits are the smoke-size ones below.  Then
 the same runs with the timed path broken underneath, and with the
 reference in float8 in the program's place: ``correct`` must read false.
+A family that is one new file runs a cell the same way, and the
+reference's readings stay those recorded at smoke size.
 """
 
 import argparse
+import json
+import sys
+from pathlib import Path
 
 import jax
 import pytest
@@ -100,11 +105,42 @@ def test_fault_half_batch(monkeypatch):
 
 @pytest.mark.parametrize("conf", [smoke.GRANITE, smoke.QWEN], ids=["granite", "qwen"])
 def test_control_train(conf):
-    from chipbench import reference as RF
     from chipbench import train_cell as TC
 
     ref = TC.reference_readings(conf, smoke.TRAIN, SEED)
-    low = TC.reference_readings(conf, smoke.TRAIN, SEED, q=RF.fp8)
+    low = TC.reference_readings(conf, smoke.TRAIN, SEED, control=True)
     got = TC.compare(low, ref)
     got.pop("_worst")
     assert any(got[k] > TRAIN_LIMITS[k] for k in TRAIN_LIMITS), got
+
+
+@pytest.mark.parametrize("conf", [smoke.GRANITE, smoke.QWEN], ids=["granite", "qwen"])
+def test_reference_readings_stay(conf):
+    """Losses and per-leaf gradient and change norms of the reference,
+    equal to the last digit to those recorded before the reference moved
+    into its family (``testdata/smoke_readings.json``)."""
+    from chipbench import train_cell as TC
+
+    recorded = json.loads((spec.ROOT / "testdata" / "smoke_readings.json").read_text())
+    assert TC.reference_readings(conf, smoke.TRAIN, SEED) == recorded[conf["name"]]
+
+
+def test_a_family_is_found_by_its_file_name(monkeypatch):
+    """A configuration naming ``wrapped_lm`` runs a cell through the
+    harness with ``correct`` true: the harness finds the family by its
+    file's name alone, and reaches the program mapping, the counts and
+    the reference only through it."""
+    from chipbench import families
+
+    name = "chipbench.families.wrapped_lm"
+    monkeypatch.setattr(families, "__path__",
+                        [*families.__path__, str(Path(__file__).parent / "families")])
+    try:
+        out = run_cell(monkeypatch, "granite-train-bdwp28",
+                       dict(smoke.GRANITE, family="wrapped_lm"), smoke.TRAIN,
+                       TRAIN_LIMITS)
+        calls = sys.modules[name].CALLS
+    finally:
+        sys.modules.pop(name, None)
+    assert out["correct"], out["checks"]
+    assert calls == {"program_config", "train_readings", "train_flops_per_token"}

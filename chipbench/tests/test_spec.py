@@ -1,7 +1,8 @@
 """Every file the benchmark finds by name loads, BENCHMARK.json keeps
 the contract's shape, the peaks table refuses an unknown device, the
-traffic generator repeats exactly for one seed, and a cell and a metric
-added as new files are found without touching the harness."""
+traffic generator repeats exactly for one seed, a cell and a metric
+added as new files are found without touching the harness, and a family
+or kind with no file is an error that names the file."""
 
 import json
 import re
@@ -54,18 +55,26 @@ def test_cell_files_load(cell):
     assert sorted(entry["reduced"]) == sorted(conf["reduced"])
     assert set(conf["reduced"]) <= set(conf.get("published", {}))
     mix = spec.traffic(cell["traffic"])
-    assert mix["kind"] == "train"
-    spec.model(conf)
+    assert spec.runner(mix["kind"]).__name__ == "chipbench.train_cell"
+    assert spec.family(conf).__name__ == "chipbench.families.decoder_lm"
     assert spec.limits(cell["name"])
     for m in spec.cell_metrics(BENCH, cell["name"], trace=True):
         assert callable(spec.metric_reader(m["name"]))
 
 
 def test_program_agrees_with_each_config():
-    from chipbench.train_cell import program_config
-
     for path in sorted((spec.ROOT / "configs").glob("*.json")):
-        program_config(spec.config(path.stem))
+        conf = spec.config(path.stem)
+        spec.family(conf).program_config(conf)
+
+
+@pytest.mark.parametrize("find,name,path", [
+    (lambda n: spec.family({"family": n}), "no_such_lm", "chipbench/families/no_such_lm.py"),
+    (lambda n: spec.family({"family": n}), "../decoder_lm", "chipbench/families/../decoder_lm.py"),
+    (spec.runner, "serve", "chipbench/serve_cell.py")], ids=["family", "path", "kind"])
+def test_unknown_family_or_kind_names_the_file(find, name, path):
+    with pytest.raises(ModuleNotFoundError, match=re.escape(f"looked for {path}")):
+        find(name)
 
 
 def test_train_batches_repeat():

@@ -47,10 +47,9 @@ def test_train_step_fits(topo, cell):
     from repro.core.sparsity import SparsityConfig
     from repro.optim import sgd
     from repro.train import step as ST
-    from chipbench.train_cell import program_config
-
     c = spec.find(spec.benchmark()["workloads"], cell, "workload")
-    cfg = program_config(spec.config(c["config"]))
+    conf = spec.config(c["config"])
+    cfg = spec.family(conf).program_config(conf)
     mix = spec.traffic(c["traffic"])
     sp = SparsityConfig(**mix["sparsity"])
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),

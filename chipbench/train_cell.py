@@ -25,36 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import AxisType, NamedSharding
 
-from chipbench import flops as FL
-from chipbench import reference as RF
 from chipbench import spec
 from chipbench import traffic as TF
-
-
-def program_config(conf: dict):
-    """The program's LMConfig for a configuration file: the architecture's
-    published config with the file's cuts; every other size must agree."""
-    from repro.configs import get_arch
-
-    base = getattr(get_arch(conf["arch"]), conf.get("preset", "full"))
-    cfg = dataclasses.replace(base, n_layers=conf["num_hidden_layers"],
-                              vocab=conf["vocab_size"])
-    want = spec.model(conf)
-    have = {"d_model": cfg.d_model, "n_heads": cfg.n_heads, "n_kv": cfg.n_kv,
-            "head_dim": cfg.head_dim, "tie_embed": cfg.tie_embed,
-            "qk_norm": cfg.qk_norm, "rope_theta": cfg.rope_theta,
-            "pad_vocab_to": cfg.pad_vocab_to, "d_ff": cfg.d_ff}
-    if cfg.moe is not None:
-        have.update(n_experts=cfg.moe.n_experts, top_k=cfg.moe.top_k,
-                    d_expert=cfg.moe.d_expert,
-                    capacity_factor=cfg.moe.capacity_factor,
-                    group_size=cfg.moe.group_size)
-    bad = {k: (v, getattr(want, k)) for k, v in have.items()
-           if v != getattr(want, k)}
-    if bad:
-        raise ValueError(f"{conf['name']}: program and file disagree "
-                         f"(program, file): {bad}")
-    return cfg
 
 
 def leaf_names(tree) -> list:
@@ -147,7 +119,7 @@ def build(conf: dict, mix: dict, log, devices) -> dict:
     from repro.train import step as ST
     from repro.train import trainer as TR
 
-    cfg = program_config(conf)
+    cfg = spec.family(conf).program_config(conf)
     sp = SparsityConfig(**mix["sparsity"])
     o = mix["optimizer"]
     opt = sgd.SGDConfig(lr=o["lr"], momentum=o["momentum"],
@@ -230,9 +202,9 @@ def window(st: dict, seconds: float, log) -> dict:
     log(f"window: step dispatch median {statistics.median(timed.dispatch):.5f} s, "
         f"max {max(timed.dispatch):.5f} s; steps over 1.5 x the median "
         f"(index in the window, s, of which dispatch): {slow}")
-    m = spec.model(st["conf"])
     sp = mix["sparsity"]
-    per_tok = FL.train_flops_per_token(m, mix["seq"], sp["n"], sp["m"])
+    per_tok = spec.family(st["conf"]).train_flops_per_token(
+        st["conf"], mix["seq"], sp["n"], sp["m"])
     log(f"work per step: {per_tok['sparse'] * mix['batch'] * mix['seq']:.4e} "
         f"FLOP as BDWP needs it, {per_tok['dense'] * mix['batch'] * mix['seq']:.4e} "
         f"dense-equivalent")
@@ -241,17 +213,16 @@ def window(st: dict, seconds: float, log) -> dict:
             "metrics": {"train_tokens_per_s": tokens / (t1 - t0)}}
 
 
-def reference_readings(conf: dict, mix: dict, seed: int, q=RF.exact,
+def reference_readings(conf: dict, mix: dict, seed: int, control: bool = False,
                        drop_half: bool = False) -> dict:
     sp, o = mix["sparsity"], mix["optimizer"]
-    m = spec.model(conf)
-    batches = [TF.train_batch(mix, m.vocab, seed, t)
+    batches = [TF.train_batch(mix, conf["vocab_size"], seed, t)
                for t in range(mix["checked_steps"])]
-    return RF.train_readings(m, jax.random.PRNGKey(TF.key_seed(seed)), batches,
-                             n=sp["n"], m=sp["m"], lr=o["lr"],
-                             momentum=o["momentum"],
-                             weight_decay=o["weight_decay"], lam=sp["lam"],
-                             q=q, drop_half=drop_half)
+    return spec.family(conf).train_readings(
+        conf, jax.random.PRNGKey(TF.key_seed(seed)), batches,
+        n=sp["n"], m=sp["m"], lr=o["lr"], momentum=o["momentum"],
+        weight_decay=o["weight_decay"], lam=sp["lam"], control=control,
+        drop_half=drop_half)
 
 
 def compare(prog: dict, ref: dict) -> dict:
